@@ -17,6 +17,7 @@ from repro.configs.base import SURFConfig
 from repro.core import baselines as BL
 from repro.core import surf, unroll as U
 from repro.data import synthetic
+from repro.utils.cache import use_compilation_cache
 
 
 def main():
@@ -45,4 +46,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compilation_cache()
     main()

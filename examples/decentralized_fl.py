@@ -36,6 +36,7 @@ from repro.core import baselines as BL
 from repro.core import surf, unroll as U
 from repro.data import synthetic
 from repro.topology import families as F
+from repro.utils.cache import use_compilation_cache
 
 
 def main(scenario="static", n_seeds=1):
@@ -93,6 +94,7 @@ def main(scenario="static", n_seeds=1):
 
 
 if __name__ == "__main__":
+    use_compilation_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--scenario", default="static",
                     choices=("static", "link-failure", "dropout"),

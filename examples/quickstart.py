@@ -25,6 +25,7 @@ from repro.configs.base import SURFConfig
 from repro.core import surf
 from repro.data import synthetic
 from repro.topology import families as F
+from repro.utils.cache import use_compilation_cache
 
 STEPS = 250
 
@@ -102,6 +103,7 @@ def main(n_seeds=1, eval_every=0):
 
 
 if __name__ == "__main__":
+    use_compilation_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--seeds", type=int, default=1,
                     help="number of training seeds batched into one "

@@ -10,8 +10,10 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.launch.serve import build_parser, main as serve_main
+from repro.utils.cache import use_compilation_cache
 
 if __name__ == "__main__":
+    use_compilation_cache()
     # same parser as the driver — only the defaults differ, so new
     # launch/serve.py flags are picked up here without duplication
     parser = build_parser()

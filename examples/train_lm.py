@@ -9,8 +9,10 @@ import sys
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.launch.train import main as train_main
+from repro.utils.cache import use_compilation_cache
 
 if __name__ == "__main__":
+    use_compilation_cache()
     args = sys.argv[1:] or ["--arch", "qwen3-4b", "--steps", "200",
                             "--batch", "8", "--seq", "128", "--lr", "3e-3",
                             "--ckpt", "bench_out/train_lm_ckpt"]

@@ -21,19 +21,6 @@ meshes are the degenerate agent-only case.
 """
 from __future__ import annotations
 
-import contextlib
-
-import jax
-
-
-def mesh_context(mesh):
-    """Version-compatible mesh scope: ``jax.set_mesh`` where it exists,
-    else the ``Mesh`` context manager (jax 0.4.x)."""
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh if hasattr(mesh, "__enter__") else contextlib.nullcontext()
-
 
 def make_ring_mix(mesh, axis: str, n: int, hops: int):
     """Returns the shard-mapped Horner graph filter ``mix_fn(W, h)`` for

@@ -235,10 +235,24 @@ def featurize_cohort(key, batch, cfg: SURFConfig, task=None):
 
 def sample_layer_batches(key, Xtr, Ytr, cfg: SURFConfig):
     """Stochastic unrolling: one independent uniform mini-batch per layer per
-    agent. Xtr (n, m, F), Ytr (n, m) -> (L, n, b, F), (L, n, b)."""
+    agent. Xtr (n, m, F), Ytr (n, m) -> (L, n, b, F), (L, n, b).
+
+    The rows are picked by a one-hot contraction, not a gather. On a TPU
+    v5e (JAX 0.9.0, libtpu 0.0.34), at the paper's widths (L=10, n=100,
+    m=45, F=512), programs holding the gather sometimes never finished;
+    with the contraction none has stalled so far, but the cause in
+    libtpu was not isolated. Each output is one row plus exact zeros,
+    and HIGHEST precision keeps every float32 bit, so the rows are the
+    gather's (``chip_smoke.py`` checks this on the chip). One difference:
+    0·Inf and 0·NaN are NaN, so a non-finite entry anywhere in an
+    agent's ``Xtr`` makes every row sampled for that agent NaN, where
+    the gather spoiled only the batches that drew that row."""
     L_, n, b = cfg.n_layers, cfg.n_agents, cfg.batch_per_agent
     m = Xtr.shape[1]
     idx = jax.random.randint(key, (L_, n, b), 0, m)
-    Xl = jnp.take_along_axis(Xtr[None].repeat(L_, 0), idx[..., None], axis=2)
-    Yl = jnp.take_along_axis(Ytr[None].repeat(L_, 0), idx, axis=2)
+    pick = idx[..., None] == jnp.arange(m)                    # (L, n, b, m)
+    Xl = jnp.einsum("lnbm,nmf->lnbf", pick.astype(Xtr.dtype), Xtr,
+                    precision=jax.lax.Precision.HIGHEST)
+    Yl = jnp.sum(jnp.where(pick, Ytr[None, :, None, :], 0), axis=-1,
+                 dtype=Ytr.dtype)
     return Xl, Yl

@@ -19,17 +19,28 @@ CI runs the sharded path on simulated host devices:
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _make_mesh(shape, axes):
+    """``jax.make_mesh`` with AUTO axes: every rule in
+    ``sharding.surf_rules`` and every ``shard_map`` here places arrays by
+    explicit ``NamedSharding``s and lets the partitioner propagate the
+    rest, which is the Auto contract. ``jax.make_mesh`` now defaults to
+    Explicit axes, whose sharding-in-types rejects e.g. a vmap over an
+    agent-sharded W beside replicated batches."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_cpu_mesh():
     """1-device mesh for smoke tests / benches (no XLA_FLAGS needed)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _make_mesh((1, 1), ("data", "model"))
 
 
 def host_device_count() -> int:
@@ -77,7 +88,7 @@ def make_surf_mesh(seed_shards: int = 1, agent_shards: int = 1, *,
             f"{need} devices but only {host_device_count()} are visible "
             f"(CI: set XLA_FLAGS=--xla_force_host_platform_device_count="
             f"{need})")
-    return jax.make_mesh((seed_shards, agent_shards), ("seed", "agent"))
+    return _make_mesh((seed_shards, agent_shards), ("seed", "agent"))
 
 
 def make_agent_mesh(n_shards: int | None = None):
@@ -94,4 +105,4 @@ def make_agent_mesh(n_shards: int | None = None):
             f"make_agent_mesh: {n} shards requested but only "
             f"{host_device_count()} devices visible (CI: set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={n})")
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return _make_mesh((n, 1), ("data", "model"))

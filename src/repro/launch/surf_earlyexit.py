@@ -46,6 +46,7 @@ from repro.core.tasks import resolve_task
 from repro.data import synthetic
 from repro.kernels.graph_filter.ops import resolve_interpret
 from repro.serve import BucketSpec, FederationServer
+from repro.utils.cache import use_compilation_cache
 
 
 def build_parser() -> argparse.ArgumentParser:
@@ -245,4 +246,5 @@ def main(argv=None, parser=None):
 
 
 if __name__ == "__main__":
+    use_compilation_cache()
     main()

@@ -132,7 +132,7 @@ def _serve_core_adaptive(cfg: SURFConfig, activation, mix_fn=None,
         W0 = jnp.where(mask[:, :, None], W0, 0.0)
         act0 = jnp.any(mask, axis=1)                 # empty slots: done
         g0 = jax.vmap(task.masked_grad_norm)(W0, Xp, Yp, mask)
-        dep0 = jnp.zeros((W0.shape[0],), jnp.int32)
+        dep0 = jnp.zeros_like(act0, jnp.int32)
 
         def layer(p_l, S1, W1, Xb1, Yb1):
             return U.udgd_layer(p_l, S1, W1, Xb1, Yb1, cfg, activation,
@@ -203,10 +203,10 @@ def request_shardings(mesh, max_batch, depth="fixed"):
     """(in_shardings, out_shardings) for a bucket solver on ``mesh``: the
     REQUEST axis (leading B on every arg and output) shards over the
     mesh's agent-role axis, theta (arg 1) replicates.  Requests are
-    embarrassingly parallel — each device solves its block of request
-    slots with ZERO collectives (the fixed path's HLO has none at all;
-    the adaptive path keeps only the scalar ``any(active)`` loop
-    predicate).  ``max_batch`` must divide the shard count — ragged
+    embarrassingly parallel — the solver runs under a ``shard_map`` with
+    these specs, so each device solves its block of request slots with
+    ZERO collectives (the adaptive path's ``any(active)`` loop predicate
+    is per device).  ``max_batch`` must divide the shard count — ragged
     tails already ride as masked empty slots, so the constraint is on
     the BUCKET batch shape, not on traffic."""
     from repro.sharding.surf_rules import (_axis_size, axis_for_role,
@@ -242,21 +242,31 @@ def make_bucket_solver(cfg: SURFConfig, bucket, max_batch, *,
 
     ``mesh`` shards the request axis over the mesh's agent-role axis
     (``request_shardings``): a bucket's (B, n_pad, ...) stacked cohorts
-    split over devices, zero collectives per request.
+    split over devices, zero collectives per request. The split is a
+    ``shard_map``, not a partitioner decision: the TPU compiler cannot
+    partition a Pallas (Mosaic) kernel on its own, so ``mix="pallas"``
+    needs each device handed its local block.
 
     ``cache`` (a ``BoundedLRU``) memoizes the executable under
     ``serve_cache_key``."""
     def build():
-        jit_kwargs = {}
-        if mesh is not None:
-            in_sh, out_sh = request_shardings(mesh, max_batch, depth)
-            jit_kwargs = {"in_shardings": in_sh, "out_shardings": out_sh}
         if depth == "adaptive":
-            return jax.jit(_serve_core_adaptive(
-                cfg, activation, mix_fn=mix_fn, task=task), **jit_kwargs)
-        solve_s = _serve_core(cfg, activation, mix_fn=mix_fn, task=task)
-        return jax.jit(jax.vmap(
-            solve_s, in_axes=(0, None, 0, 0, 0, 0, 0, 0, 0)), **jit_kwargs)
+            solve = _serve_core_adaptive(cfg, activation, mix_fn=mix_fn,
+                                         task=task)
+        else:
+            solve = jax.vmap(
+                _serve_core(cfg, activation, mix_fn=mix_fn, task=task),
+                in_axes=(0, None, 0, 0, 0, 0, 0, 0, 0))
+        if mesh is None:
+            return jax.jit(solve)
+        in_sh, out_sh = request_shardings(mesh, max_batch, depth)
+        # jax has no varying-axis rule for pallas_call (as in
+        # topology.halo's Pallas resident); the dense path keeps the check
+        solve = jax.shard_map(solve, mesh=mesh,
+                              in_specs=tuple(s.spec for s in in_sh),
+                              out_specs=out_sh.spec,
+                              check_vma=mix_fn is None)
+        return jax.jit(solve, in_shardings=in_sh, out_shardings=out_sh)
 
     if cache is None:
         return build()

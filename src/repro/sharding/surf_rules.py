@@ -194,7 +194,6 @@ def make_q_select(mesh: Mesh, axis):
     the selected batch is BIT-equal to the replicated index. ``n_q`` is
     derived from the local block (global dim 0 = local · shards), so one
     select serves every pool size."""
-    from jax.experimental.shard_map import shard_map
     n_shards = int(mesh.shape[axis])
 
     def select(stacked, t):
@@ -210,8 +209,8 @@ def make_q_select(mesh: Mesh, axis):
                 return jax.lax.psum(masked, axis)
             return jax.tree_util.tree_map(one, local)
 
-        return shard_map(body, mesh=mesh, in_specs=(P(axis), P()),
-                         out_specs=P())(stacked, t)
+        return jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P()),
+                             out_specs=P())(stacked, t)
 
     return select
 

@@ -65,11 +65,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-try:                                   # jax >= 0.5: public top-level API
-    _shard_map = jax.shard_map
-except AttributeError:                 # pinned jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def _check_divisible(n, nshards, what="halo plan"):
     """Every halo planner fails an indivisible agent axis HERE with the
@@ -105,6 +100,19 @@ def _resident_matmul(resident):
     return lambda S0, Y: graph_filter(S0, Y, one_hop, impl="pallas")
 
 
+def _row_take(rows):
+    """``Y -> Y[rows]`` for a static sorted row set, as static slices of
+    its consecutive runs. A gather here would transpose to a
+    ``scatter_add`` whose index operand misses the 'seed' axis that the
+    seed vmap's ``spmd_axis_name`` adds to Y, which shard_map's
+    varying-axis check refuses; slices transpose to pads."""
+    rows = np.asarray(rows)
+    runs = np.split(rows, np.nonzero(np.diff(rows) != 1)[0] + 1)
+    bounds = [(int(r[0]), int(r[-1]) + 1) for r in runs]
+    return lambda Y: jnp.concatenate(
+        [jax.lax.slice_in_dim(Y, a, b, axis=0) for a, b in bounds], axis=0)
+
+
 def _halo_filter_smapped(mesh, axis, row_sets, perms, resident="dense"):
     """The shared shard-mapped K-tap Horner graph filter
     ``(W_loc, h, S0_loc, Sd_locs) -> Y_loc`` over the AGENT sub-axis
@@ -120,12 +128,13 @@ def _halo_filter_smapped(mesh, axis, row_sets, perms, resident="dense"):
     sub-axis. ``resident`` selects the on-shard block engine
     (``_resident_matmul``)."""
     res_mm = _resident_matmul(resident)
+    takes = [_row_take(rows) for rows in row_sets]
 
     def apply_S(Y, S0_loc, Sd_locs):
         # Y (nl, d) local block; S0_loc (1, nl, nl); Sd_locs[i] (1, nl, r_i)
         out = res_mm(S0_loc[0], Y)
-        for rows, perm, Sd in zip(row_sets, perms, Sd_locs):
-            recv = jax.lax.ppermute(Y[rows], axis, perm)
+        for take, perm, Sd in zip(takes, perms, Sd_locs):
+            recv = jax.lax.ppermute(take(Y), axis, perm)
             out = out + Sd[0] @ recv
         return out
 
@@ -136,14 +145,14 @@ def _halo_filter_smapped(mesh, axis, row_sets, perms, resident="dense"):
             Y = apply_S(Y, S0_loc, Sd_locs) + h[k] * W_loc
         return Y
 
-    # jax has no replication rule for pallas_call inside shard_map; the
+    # jax has no varying-axis rule for pallas_call inside shard_map; the
     # specs here are fully explicit (every input/output names its axis),
-    # so disabling the redundant rep check for the pallas resident is
-    # safe — the dense resident keeps the default checking.
-    return _shard_map(
+    # so disabling the redundant check for the pallas resident is safe —
+    # the dense resident keeps the default checking.
+    return jax.shard_map(
         filter_local, mesh=mesh,
         in_specs=(P(axis), P(), P(axis), tuple(P(axis) for _ in row_sets)),
-        out_specs=P(axis), check_rep=(resident == "dense"))
+        out_specs=P(axis), check_vma=(resident == "dense"))
 
 
 def _offset_perms(plans, nshards):

@@ -17,6 +17,10 @@ leaking through the registry. ``clear_caches()`` empties every live
 registered cache (or just the named ones); ``cache_stats()`` returns a
 per-cache stats snapshot.
 
+``use_compilation_cache()`` is the one place an entry point turns on
+JAX's PERSISTENT compilation cache (compiled executables on disk, shared
+across processes) — see its docstring.
+
 Stats semantics: ``hits`` counts item lookups (``cache[key]``),
 ``misses`` counts ``get_or_build`` calls that had to build, ``inserts``
 counts stores, ``evictions`` counts LRU drops. Call sites using the
@@ -26,10 +30,34 @@ inserts; ``get_or_build`` accounts both.
 from __future__ import annotations
 
 import itertools
+import os
 import threading
 import weakref
 from collections import OrderedDict
 from collections.abc import MutableMapping
+
+# <repo>/.jax_cache — this file is <repo>/src/repro/utils/cache.py
+REPO_COMPILATION_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def use_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it as
+    ``jax_compilation_cache_dir`` and this sets nothing. Otherwise the
+    cache goes to the fixed ``<repo>/.jax_cache``, never a per-run name,
+    so each run finds what the previous one compiled. Call it from a
+    ``__main__`` path: never at import, and never from the tests."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+    jax.config.update("jax_compilation_cache_dir", REPO_COMPILATION_CACHE)
+    return REPO_COMPILATION_CACHE
+
 
 _registry_lock = threading.Lock()
 _REGISTRY: "OrderedDict[str, weakref.ref]" = OrderedDict()

@@ -49,7 +49,7 @@ def test_blockwise_flag_preserves_model_output(key):
 def test_ring_mix_equals_dense_metropolis():
     """The ppermute ring filter == dense metropolis circulant (1-device
     mesh wraps locally, same math as the P-shard halo exchange)."""
-    from repro.core.ring import dense_equivalent, make_ring_mix, mesh_context
+    from repro.core.ring import dense_equivalent, make_ring_mix
     from repro.core.unroll import graph_filter
     n, d, hops = 16, 12, 2
     mesh = jax.make_mesh((1, 1), ("data", "model"))
@@ -57,7 +57,7 @@ def test_ring_mix_equals_dense_metropolis():
     S = jnp.asarray(dense_equivalent(n, hops), jnp.float32)
     W = jax.random.normal(jax.random.PRNGKey(0), (n, d))
     h = jnp.array([0.25, 0.6, 0.15])
-    with mesh_context(mesh):
+    with jax.set_mesh(mesh):
         y_ring = mix(W, h)
     y_dense = graph_filter(S, W, h)
     np.testing.assert_allclose(np.asarray(y_ring), np.asarray(y_dense),

@@ -168,28 +168,36 @@ def served():
 
 
 @multi_device
+@pytest.mark.parametrize("depth", ["fixed", "adaptive"])
 @pytest.mark.parametrize("mix", [None, "pallas"])
-def test_sharded_serve_matches_solo_solve(served, mix):
+def test_sharded_serve_matches_solo_solve(served, mix, depth):
     """Request axis sharded over 8 devices (zero collectives — each
     device solves its block of slots): every ragged request matches the
     single-cohort ``solve_federation`` reference, including partially
-    full batches riding as masked empty slots."""
+    full batches riding as masked empty slots. The adaptive-depth solver
+    runs its early-exit loop per device and must realize the reference
+    depth."""
     state, _ = served
-    srv = FederationServer(SMOKE, state.theta, mix=mix, max_batch=8,
+    cfg = (dataclasses.replace(SMOKE, exit_threshold=0.05, min_layers=2)
+           if depth == "adaptive" else SMOKE)
+    srv = FederationServer(cfg, state.theta, mix=mix, max_batch=8,
                            buckets=BucketSpec(agent_sizes=(8, 16),
                                               row_sizes=(4, 8)),
-                           mesh=make_surf_mesh(1, 8))
-    reqs = [_cohort(SMOKE, n, t, seed=50 + i)
+                           depth=depth, mesh=make_surf_mesh(1, 8))
+    reqs = [_cohort(cfg, n, t, seed=50 + i)
             for i, (n, t) in enumerate([(6, 4), (8, 4), (12, 4), (16, 4),
                                         (14, 4), (10, 4)])]
     futs = [srv.submit(S, ds, seed=i) for i, (_, S, ds) in enumerate(reqs)]
     srv.drain()
     tol = 5e-4 if mix == "pallas" else 5e-5
     for i, ((cfg_r, S, ds), fut) in enumerate(zip(reqs, futs)):
-        ref = surf.solve_federation(cfg_r, state, S, ds, seed=i)
+        ref = surf.solve_federation(cfg_r, state, S, ds, seed=i,
+                                    depth=depth)
         res = fut.result()
         assert abs(float(res["final_loss"] - ref["final_loss"])) < tol
         assert abs(float(res["final_acc"] - ref["final_acc"])) < tol
+        if depth == "adaptive":
+            assert int(res["depth"]) == round(float(ref["depth"]))
 
 
 @multi_device

@@ -67,6 +67,22 @@ def test_udgd_forward_shapes(problem, key):
     assert W_all.shape == (CFG.n_layers + 1, CFG.n_agents, CFG.head_dim)
 
 
+def test_sample_layer_batches_picks_rows_exactly(problem, key):
+    """The one-hot row pick returns bit-for-bit the rows a gather of the
+    same uniform indices returns."""
+    _, _, mds = problem
+    Xtr, Ytr = mds[0]["Xtr"], mds[0]["Ytr"]
+    Xl, Yl = U.sample_layer_batches(key, jnp.asarray(Xtr), jnp.asarray(Ytr),
+                                    CFG)
+    idx = np.asarray(jax.random.randint(
+        key, (CFG.n_layers, CFG.n_agents, CFG.batch_per_agent), 0,
+        Xtr.shape[1]))
+    agents = np.arange(CFG.n_agents)[None, :, None]
+    np.testing.assert_array_equal(np.asarray(Xl), Xtr[agents, idx])
+    np.testing.assert_array_equal(np.asarray(Yl), Ytr[agents, idx])
+    assert Yl.dtype == Ytr.dtype
+
+
 def test_star_server_row_only_aggregates(key):
     import dataclasses
     cfg = dataclasses.replace(CFG, topology="star", filter_taps=1)

@@ -207,7 +207,9 @@ def test_sparse_recovery_trains_through_engine(sparse_mds):
                                      log_every=4)
     losses = [h["test_loss"] for h in hist]
     assert all(np.isfinite(losses))
-    assert losses[-1] < losses[0]
+    # each logged point is one noisy meta-step: compare window means
+    third = len(losses) // 3
+    assert np.mean(losses[-third:]) < np.mean(losses[:third])
     # "acc" slots generically carry the task metric (NMSE, lower=better)
     assert np.isfinite(hist[-1]["test_acc"])
     ev = surf.evaluate_surf(SCFG, state, S, sparse_mds, seed=0,
