@@ -73,10 +73,12 @@ class Task:
     # ------------------------------------------------- shared FL lifts
     def fl_loss(self, W, X, Y):
         """f(W) = (1/n) Σ_i f_i(w_i).  W (n,d), X (n,b,F), Y (n,b)."""
-        return jnp.mean(jax.vmap(self.local_loss)(W, X, Y))
+        with jax.named_scope("surf/loss"):
+            return jnp.mean(jax.vmap(self.local_loss)(W, X, Y))
 
     def fl_metric(self, W, X, Y):
-        return jnp.mean(jax.vmap(self.local_metric)(W, X, Y))
+        with jax.named_scope("surf/loss"):
+            return jnp.mean(jax.vmap(self.local_metric)(W, X, Y))
 
     def fl_grad(self, W, X, Y):
         """Stochastic ∇f(W) ∈ R^{n×d} — row i is ∇f_i(w_i)/n."""

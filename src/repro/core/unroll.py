@@ -44,11 +44,12 @@ def _mix(mix_fn, S, W, h):
       * otherwise — ``mix_fn(W, h)``: a baked-S collective exchange
         (ring / halo ``ppermute`` paths of ``core.ring`` /
         ``topology.halo``)."""
-    if mix_fn is None:
-        return graph_filter(S, W, h)
-    if getattr(mix_fn, "takes_S", False):
-        return mix_fn(S, W, h)
-    return mix_fn(W, h)
+    with jax.named_scope("surf/mix"):
+        if mix_fn is None:
+            return graph_filter(S, W, h)
+        if getattr(mix_fn, "takes_S", False):
+            return mix_fn(S, W, h)
+        return mix_fn(W, h)
 
 
 def batch_vector(Xb, Yb, n_classes):
@@ -89,6 +90,15 @@ def init_udgd(key, cfg: SURFConfig, dtype=jnp.float32, init="dgd", task=None):
     return {"h": h.astype(dtype), "M": M.astype(dtype), "d": dd.astype(dtype)}
 
 
+def _perceptron(W, Xb, Yb, M, d, activation, task):
+    """σ(M [w ∥ b] + d) per agent: the shared perceptron's local step."""
+    with jax.named_scope("surf/perceptron"):
+        b_in = task.batch_vector(Xb, Yb)
+        z = jnp.concatenate([W, b_in], axis=-1) @ M + d      # (n, d)
+        act = {"relu": jax.nn.relu, "tanh": jnp.tanh}[activation]
+        return act(z)
+
+
 def udgd_layer(params_l, S, W, Xb, Yb, cfg: SURFConfig, activation="relu",
                mix_fn=None, task=None):
     """One unrolled layer. W (n,d); Xb (n,b,F); Yb (n,b). ``mix_fn(W, h)``
@@ -98,10 +108,7 @@ def udgd_layer(params_l, S, W, Xb, Yb, cfg: SURFConfig, activation="relu",
     task = resolve_task(cfg, task)
     h, M, d = params_l["h"], params_l["M"], params_l["d"]
     mixed = _mix(mix_fn, S, W, h)
-    b_in = task.batch_vector(Xb, Yb)
-    z = jnp.concatenate([W, b_in], axis=-1) @ M + d      # (n, d)
-    act = {"relu": jax.nn.relu, "tanh": jnp.tanh}[activation]
-    return mixed - act(z)
+    return mixed - _perceptron(W, Xb, Yb, M, d, activation, task)
 
 
 def udgd_forward(params, S, W0, Xl, Yl, cfg: SURFConfig, activation="relu",
@@ -205,14 +212,13 @@ def udgd_layer_star(params_l, S, W, Xb, Yb, cfg: SURFConfig,
     task = resolve_task(cfg, task)
     h, M, d = params_l["h"], params_l["M"], params_l["d"]
     mixed = _mix(mix_fn, S, W, h)
-    b_in = task.batch_vector(Xb, Yb)
-    z = jnp.concatenate([W, b_in], axis=-1) @ M + d
-    act = {"relu": jax.nn.relu, "tanh": jnp.tanh}[activation]
-    return mixed - star_filter_mask(cfg) * act(z)
+    return mixed - star_filter_mask(cfg) * _perceptron(W, Xb, Yb, M, d,
+                                                       activation, task)
 
 
 def sample_w0(key, cfg: SURFConfig, task=None):
-    return resolve_task(cfg, task).init_state(key, cfg)
+    with jax.named_scope("surf/featurize"):
+        return resolve_task(cfg, task).init_state(key, cfg)
 
 
 def featurize_cohort(key, batch, cfg: SURFConfig, task=None):
@@ -249,10 +255,11 @@ def sample_layer_batches(key, Xtr, Ytr, cfg: SURFConfig):
     the gather spoiled only the batches that drew that row."""
     L_, n, b = cfg.n_layers, cfg.n_agents, cfg.batch_per_agent
     m = Xtr.shape[1]
-    idx = jax.random.randint(key, (L_, n, b), 0, m)
-    pick = idx[..., None] == jnp.arange(m)                    # (L, n, b, m)
-    Xl = jnp.einsum("lnbm,nmf->lnbf", pick.astype(Xtr.dtype), Xtr,
-                    precision=jax.lax.Precision.HIGHEST)
-    Yl = jnp.sum(jnp.where(pick, Ytr[None, :, None, :], 0), axis=-1,
-                 dtype=Ytr.dtype)
+    with jax.named_scope("surf/featurize"):
+        idx = jax.random.randint(key, (L_, n, b), 0, m)
+        pick = idx[..., None] == jnp.arange(m)                # (L, n, b, m)
+        Xl = jnp.einsum("lnbm,nmf->lnbf", pick.astype(Xtr.dtype), Xtr,
+                        precision=jax.lax.Precision.HIGHEST)
+        Yl = jnp.sum(jnp.where(pick, Ytr[None, :, None, :], 0), axis=-1,
+                     dtype=Ytr.dtype)
     return Xl, Yl

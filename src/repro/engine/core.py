@@ -121,14 +121,16 @@ def _meta_step_core(cfg: SURFConfig, constrained, activation, star, mix_fn,
     def lagrangian_fn(theta, lam, S, W0, Xl, Yl, Xte, Yte, mf, kp):
         W_L, W_all = _forward(S, theta, W0, Xl, Yl, mf)
         test_loss = task.fl_loss(W_L, Xte, Yte)
-        gnorms = C.layer_grad_norms(W_all, Xl, Yl, cfg, task=task)
-        if robust:
-            g_rob = C.robust_layer_grad_norms(W_all, Xl, Yl, cfg, kp,
-                                              task=task, nominal=gnorms)
-            slack = C.robust_slacks(g_rob, gnorms, cfg.eps)
-        else:
-            slack = C.slacks(gnorms, cfg.eps)
-        lag = C.lagrangian(test_loss, lam, slack) if constrained else test_loss
+        with jax.named_scope("surf/constraints"):
+            gnorms = C.layer_grad_norms(W_all, Xl, Yl, cfg, task=task)
+            if robust:
+                g_rob = C.robust_layer_grad_norms(W_all, Xl, Yl, cfg, kp,
+                                                  task=task, nominal=gnorms)
+                slack = C.robust_slacks(g_rob, gnorms, cfg.eps)
+            else:
+                slack = C.slacks(gnorms, cfg.eps)
+            lag = (C.lagrangian(test_loss, lam, slack) if constrained
+                   else test_loss)
         return lag, (test_loss, slack, gnorms, W_L)
 
     def meta_step_s(S, state: TrainState, batch, key, mix_blocks=None):
@@ -155,11 +157,14 @@ def _meta_step_core(cfg: SURFConfig, constrained, activation, star, mix_fn,
             lagrangian_fn, has_aux=True)(state.theta, state.lam, S, W0, Xl,
                                          Yl, batch["Xte"], batch["Yte"], mf,
                                          kp)
-        grads, gn = clip_by_global_norm(grads, 10.0)
-        upd, opt_state = opt.update(grads, state.opt_state)
-        theta = apply_updates(state.theta, upd)
-        lam = (C.dual_ascent(state.lam, slack, cfg.lr_lambda)
-               if constrained else state.lam)
+        with jax.named_scope("surf/clip"):
+            grads, gn = clip_by_global_norm(grads, 10.0)
+        with jax.named_scope("surf/adam"):
+            upd, opt_state = opt.update(grads, state.opt_state)
+            theta = apply_updates(state.theta, upd)
+        with jax.named_scope("surf/dual"):
+            lam = (C.dual_ascent(state.lam, slack, cfg.lr_lambda)
+                   if constrained else state.lam)
         test_acc = task.fl_metric(W_L, batch["Xte"], batch["Yte"])
         metrics = {"lagrangian": lag, "test_loss": tl, "test_acc": test_acc,
                    "slack_max": jnp.max(slack), "slack_mean": jnp.mean(slack),

@@ -22,7 +22,7 @@ absolute throughput is a correctness-path number, not accelerator perf.
 A ``sharded_async`` section then replays a trace prefix per shard count
 through a MESH-SHARDED server (request axis placed over 'agent'-axis
 devices, ``serve.request_shardings``) driven by ``serve.AsyncDriver`` —
-federations/s vs shards + tick utilization + parity spot-checks, with
+federations/s vs shards + tick counts + parity spot-checks, with
 ``jax.device_count()``/mesh fingerprints stamped and the simulated-
 device caveat made explicit (forced host CPU devices share one chip).
 
@@ -104,7 +104,7 @@ def bench_sharded_async(cfg, state, trace, args, sizes, rows, tol):
     """The sharded+async rows: replay a trace prefix through a
     mesh-sharded server (request axis over 'agent'-axis devices) driven
     by ``AsyncDriver``, one row per shard count — federations/s vs
-    shards, tick utilization, and a per-row parity spot-check vs the
+    shards, tick count, and a per-row parity spot-check vs the
     solo reference solve.  On forced-host CPU devices the shards share
     one physical CPU, so rows track PLACEMENT overhead (zero-collective
     claim), not real scaling — the caveat is stamped."""
@@ -152,14 +152,13 @@ def bench_sharded_async(cfg, state, trace, args, sizes, rows, tol):
                "async_wall_s": round(wall, 3),
                "async_federations_per_sec": (len(sub) / wall
                                              if wall > 0 else 0.0),
-               "tick_utilization": round(stats["tick_utilization"], 3),
                "ticks": stats["ticks"],
                "parity_spot_max_delta": max_d,
                "bucket_cache": server.cache_stats()}
         out.append(row)
         print(f"sharded+async shards={shards}: "
               f"{row['async_federations_per_sec']:.1f} federations/s "
-              f"util={row['tick_utilization']:.2f} parity={max_d:.2e}")
+              f"parity={max_d:.2e}")
     return out
 
 

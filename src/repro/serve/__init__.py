@@ -21,6 +21,23 @@ futures, deadline-aware admission), ``driver`` (``AsyncDriver`` — a
 background tick thread so ``submit`` returns immediately), ``metrics``
 (throughput/latency/pad-waste/cache telemetry).  The CLI driver is
 ``repro.launch.surf_serve``.
+
+Where a tick's time goes: run the server under a profiler session,
+
+    with jax.profiler.trace("serve-trace"):
+        ...submit and tick...
+    recs = repro.utils.spans.records()
+
+and each ``submit`` leaves ``serve.submit`` (attribute ``req``, the
+request's id) with ``serve.submit.featurize`` and ``serve.submit.pad``
+inside it, and each ``tick`` leaves ``serve.tick`` (``reqs``, the ids it
+admitted; ``bucket``) with ``serve.tick.admit``, ``.stack``, ``.call``
+(``bytes_in``, host bytes handed to the solver, θ excluded), ``.wait``
+and ``.unpack`` inside it.  Each is a record in ``recs`` (start, end,
+thread CPU seconds, parent) and a ``surf.*`` host event in the profile,
+beside the solver's device operations, which carry the ``surf/mix``,
+``surf/perceptron`` and ``surf/loss`` scopes.  Without a profiler
+session nothing is recorded (``repro.utils.spans``).
 """
 from repro.serve.buckets import Bucket, BucketSpec, pad_cohort, pad_probe
 from repro.serve.driver import AsyncDriver

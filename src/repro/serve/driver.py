@@ -29,10 +29,10 @@ Semantics:
     queue, then joins the thread; ``stop(drain=False)`` exits after the
     in-flight tick, leaving queued requests pending (the server is
     untouched — a later ``server.drain()`` completes them).
-  * METRICS — ``stats()`` reports the loop's tick utilization
-    (``busy_s / wall_s`` — the fraction of driver wall time spent
-    inside solves) next to tick/request counts; ``server.metrics``
-    keeps the solve-side telemetry.
+  * METRICS — ``stats()`` reports tick and request counts;
+    ``server.metrics`` keeps the solve-side telemetry, and the
+    ``serve.tick`` spans (``repro.utils.spans``) time the loop's work
+    while a profiler session runs.
 """
 from __future__ import annotations
 
@@ -54,9 +54,6 @@ class AsyncDriver:
         self._thread = None
         self._running = False
         self._drain_on_stop = True
-        self._started_at = None
-        self._stopped_wall = 0.0         # accumulated across start/stop
-        self.busy_s = 0.0                # seconds inside server.tick()
         self.ticks = 0                   # non-empty ticks fired
         self.empty_polls = 0             # wake-ups that found no work
         self.completed = 0               # requests completed by the loop
@@ -74,9 +71,7 @@ class AsyncDriver:
                     self.empty_polls += 1
                     self._wake.wait(timeout=0.05)
                     continue
-            t0 = time.perf_counter()
             done = self.server.tick()
-            self.busy_s += time.perf_counter() - t0
             if done:
                 self.ticks += 1
                 self.completed += done
@@ -89,7 +84,6 @@ class AsyncDriver:
         if self._thread is not None and self._thread.is_alive():
             return self
         self._running = True
-        self._started_at = time.perf_counter()
         self._thread = threading.Thread(target=self._loop,
                                         name="serve-tick", daemon=True)
         self._thread.start()
@@ -109,9 +103,6 @@ class AsyncDriver:
                 raise TimeoutError(
                     f"serve-tick thread did not stop within {timeout_s}s")
             self._thread = None
-        if self._started_at is not None:
-            self._stopped_wall += time.perf_counter() - self._started_at
-            self._started_at = None
         return self
 
     def __enter__(self):
@@ -146,19 +137,12 @@ class AsyncDriver:
 
     # ----------------------------------------------------------- stats
     def stats(self) -> dict:
-        """Loop-side telemetry: ``tick_utilization`` is busy_s/wall_s —
-        the fraction of driver wall time spent inside solves (1.0 ≈
-        solve-bound, ~0 ≈ idle/cadence-bound)."""
-        wall = self._stopped_wall
-        if self._started_at is not None:
-            wall += time.perf_counter() - self._started_at
+        """Loop-side counts: non-empty ticks, empty polls, requests
+        completed."""
         return {
             "ticks": self.ticks,
             "empty_polls": self.empty_polls,
             "requests_completed": self.completed,
-            "busy_s": self.busy_s,
-            "wall_s": wall,
-            "tick_utilization": (self.busy_s / wall if wall > 0 else 0.0),
             "interval_s": self.interval_s,
             "running": bool(self._thread is not None
                             and self._thread.is_alive()),

@@ -5,11 +5,11 @@ and pad waste.
 per solver tick; ``summary()`` condenses them into the numbers
 ``launch.surf_serve`` stamps into ``BENCH_serve.json``:
 
-  * ``federations_per_sec`` — completed requests over total solve wall
-    time (and a ``rolling_`` variant over the last ``window`` ticks,
-    the steady-state number once compiles are off the path);
-  * ``latency_p50_ms`` / ``latency_p99_ms`` — enqueue→complete, so
-    queueing delay counts, exactly what a caller observes;
+  * ``federations_per_sec`` — completed requests over wall time, from
+    the earliest submit of a completed request to the last completion;
+  * ``latency_p50_ms`` / ``latency_p99_ms`` — from ``submit``'s entry to
+    completion, so featurization and queueing count, exactly what a
+    caller observes;
   * ``occupancy`` — admitted requests over offered batch slots (low
     occupancy = the stream is too fragmented for ``max_batch``);
   * ``pad_waste`` — 1 − useful/padded compute cells, where a cell is
@@ -28,13 +28,13 @@ per solver tick; ``summary()`` condenses them into the numbers
 """
 from __future__ import annotations
 
-from collections import deque
+import time
 
 import numpy as np
 
 
 class ServeMetrics:
-    def __init__(self, window: int = 64, cache=None):
+    def __init__(self, cache=None):
         # the server's bucket-executable BoundedLRU; its live stats()
         # ride along in every summary() snapshot
         self.cache = cache
@@ -42,12 +42,13 @@ class ServeMetrics:
         self.completed = 0
         self.ticks = 0
         self.solve_time = 0.0            # seconds inside solver calls
+        self.first_submit = None         # perf_counter, earliest completed
+        self.last_done = None            # perf_counter, latest completion
         self.slots_offered = 0           # max_batch per tick
         self.admitted = 0
         self.useful_cells = 0.0          # Σ n_real * t_real over requests
         self.padded_cells = 0.0          # Σ slots * n_pad * t_pad over ticks
         self.per_bucket = {}             # bucket -> tick count
-        self._window = deque(maxlen=window)   # (wall, n_admitted) per tick
         self.depth_hist = {}             # realized depth -> request count
         self.layers_run = 0              # Σ while-loop trips over ticks
         self.adaptive_ticks = 0
@@ -55,10 +56,11 @@ class ServeMetrics:
 
     def record_tick(self, bucket, n_admitted, slots, useful_cells,
                     padded_cells, latencies, wall, depths=None,
-                    layers_run=None, n_layers=None):
+                    layers_run=None, n_layers=None, done_at=None):
         """One solver invocation: ``n_admitted`` requests in ``slots``
-        batch slots of ``bucket``, per-request enqueue→complete
-        ``latencies`` (seconds), ``wall`` seconds in the solve.
+        batch slots of ``bucket``, per-request submit→complete
+        ``latencies`` (seconds), ``wall`` seconds in the solve, completed
+        at ``done_at`` (``time.perf_counter``; now when omitted).
         Adaptive servers also pass per-request realized ``depths``, the
         tick's while-loop trip count ``layers_run`` and the model depth
         ``n_layers``."""
@@ -70,9 +72,15 @@ class ServeMetrics:
         self.useful_cells += float(useful_cells)
         self.padded_cells += float(padded_cells)
         self.latencies.extend(float(x) for x in latencies)
+        if latencies:
+            done_at = time.perf_counter() if done_at is None else done_at
+            first = done_at - max(latencies)
+            if self.first_submit is None or first < self.first_submit:
+                self.first_submit = first
+            if self.last_done is None or done_at > self.last_done:
+                self.last_done = done_at
         key = tuple(bucket)
         self.per_bucket[key] = self.per_bucket.get(key, 0) + 1
-        self._window.append((float(wall), int(n_admitted)))
         if depths is not None:
             self.adaptive_ticks += 1
             self.layers_run += int(layers_run)
@@ -83,15 +91,13 @@ class ServeMetrics:
 
     def summary(self) -> dict:
         lat = np.asarray(self.latencies, np.float64)
-        w_wall = sum(w for w, _ in self._window)
-        w_n = sum(n for _, n in self._window)
+        span = (self.last_done - self.first_submit
+                if self.last_done is not None else 0.0)
         out = {
             "requests_completed": self.completed,
             "ticks": self.ticks,
-            "federations_per_sec": (self.completed / self.solve_time
-                                    if self.solve_time > 0 else 0.0),
-            "rolling_federations_per_sec": (w_n / w_wall
-                                            if w_wall > 0 else 0.0),
+            "federations_per_sec": (self.completed / span
+                                    if span > 0 else 0.0),
             "latency_p50_ms": (float(np.percentile(lat, 50)) * 1e3
                                if lat.size else 0.0),
             "latency_p99_ms": (float(np.percentile(lat, 99)) * 1e3

@@ -39,6 +39,7 @@ task) in a per-server ``BoundedLRU`` (registered as "serve-buckets" for
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import threading
 import time
 from collections import deque
@@ -53,6 +54,7 @@ from repro.core.tasks import resolve_task
 from repro.serve.buckets import BucketSpec, pad_cohort, pad_probe
 from repro.serve.metrics import ServeMetrics
 from repro.serve.solver import make_bucket_solver, resolve_serve_mix
+from repro.utils import spans
 from repro.utils.cache import BoundedLRU
 
 _REQUIRED = ("Xtr", "Ytr", "Xte", "Yte")
@@ -90,7 +92,8 @@ class _Request:
     n_real: int
     rows_real: int
     future: ServeFuture
-    t_submit: float
+    t_submit: float                      # perf_counter at submit's entry
+    rid: int                             # per-server request id (spans)
     ticks_waited: int = 0                # ticks passed over (aging input)
     deadline_ticks: int | None = None    # admission deadline (optional)
 
@@ -143,6 +146,7 @@ class FederationServer:
         self._cache = BoundedLRU(maxsize=max_buckets, name="serve-buckets")
         self.metrics = ServeMetrics(cache=self._cache)
         self._queue = deque()
+        self._ids = itertools.count()
         # guards queue mutations only (submit's append, tick's admission
         # sweep) so an async driver can tick while submits keep landing;
         # the solve itself runs outside the lock
@@ -167,50 +171,59 @@ class FederationServer:
         miss its deadline if passed over again (most-urgent first),
         ahead of the aging and fullest-bucket rules
         (``_select_bucket``)."""
-        if deadline_ticks is not None and int(deadline_ticks) < 1:
-            raise ValueError(f"deadline_ticks must be >= 1, got "
-                             f"{deadline_ticks}")
-        S = np.asarray(S, np.float32)
-        if S.ndim != 2 or S.shape[0] != S.shape[1]:
-            raise ValueError(f"S must be square (n, n), got {S.shape}")
-        n = S.shape[0]
-        missing = [k for k in _REQUIRED if k not in dataset]
-        if missing:
-            raise ValueError(f"dataset missing keys {missing}")
-        for k in _REQUIRED:
-            if np.asarray(dataset[k]).shape[0] != n:
-                raise ValueError(
-                    f"dataset[{k!r}] leads with {np.asarray(dataset[k]).shape[0]} "
-                    f"agents but S is {n}x{n}")
-        cfg_r = dataclasses.replace(self.cfg, n_agents=n)
-        key = jax.random.fold_in(jax.random.PRNGKey(1000 + int(seed)),
-                                 int(q))
-        batch = {k: jnp.asarray(np.asarray(dataset[k])) for k in _REQUIRED}
-        W0, Xl, Yl = U.featurize_cohort(key, batch, cfg_r, task=self.task)
-        t = int(np.asarray(dataset["Xte"]).shape[1])
-        bucket = self.buckets.bucket_for(n, t)
-        Sp, W0p, Xlp, Ylp, Xtep, Ytep, mask, t_real = pad_cohort(
-            S, W0, Xl, Yl, dataset["Xte"], dataset["Yte"], bucket)
-        arrays = (Sp, W0p, Xlp, Ylp, Xtep, Ytep)
-        if self.depth == "adaptive":
-            m = int(np.asarray(dataset["Xtr"]).shape[1])
-            if m < self.cfg.probe_size:
-                raise ValueError(
-                    f"adaptive serving needs probe_size="
-                    f"{self.cfg.probe_size} training rows per agent for "
-                    f"the convergence probe, got {m} — probe rows must "
-                    "be shape-constant per bucket executable")
-            Xp, Yp = U.probe_batch(batch, cfg_r)
-            arrays = arrays + pad_probe(Xp, Yp, bucket)
-        fut = ServeFuture()
-        req = _Request(
-            bucket=bucket, arrays=arrays,
-            mask=mask, t_real=t_real, n_real=n, rows_real=t, future=fut,
-            t_submit=time.perf_counter(),
-            deadline_ticks=(None if deadline_ticks is None
-                            else int(deadline_ticks)))
-        with self._lock:
-            self._queue.append(req)
+        t_submit = time.perf_counter()
+        rid = next(self._ids)
+        with spans.span("serve.submit", req=rid):
+            if deadline_ticks is not None and int(deadline_ticks) < 1:
+                raise ValueError(f"deadline_ticks must be >= 1, got "
+                                 f"{deadline_ticks}")
+            S = np.asarray(S, np.float32)
+            if S.ndim != 2 or S.shape[0] != S.shape[1]:
+                raise ValueError(f"S must be square (n, n), got {S.shape}")
+            n = S.shape[0]
+            missing = [k for k in _REQUIRED if k not in dataset]
+            if missing:
+                raise ValueError(f"dataset missing keys {missing}")
+            for k in _REQUIRED:
+                if np.asarray(dataset[k]).shape[0] != n:
+                    raise ValueError(
+                        f"dataset[{k!r}] leads with "
+                        f"{np.asarray(dataset[k]).shape[0]} agents but S is "
+                        f"{n}x{n}")
+            cfg_r = dataclasses.replace(self.cfg, n_agents=n)
+            with spans.span("serve.submit.featurize"):
+                key = jax.random.fold_in(
+                    jax.random.PRNGKey(1000 + int(seed)), int(q))
+                batch = {k: jnp.asarray(np.asarray(dataset[k]))
+                         for k in _REQUIRED}
+                W0, Xl, Yl = (np.asarray(a) for a in U.featurize_cohort(
+                    key, batch, cfg_r, task=self.task))
+            with spans.span("serve.submit.pad"):
+                t = int(np.asarray(dataset["Xte"]).shape[1])
+                bucket = self.buckets.bucket_for(n, t)
+                Sp, W0p, Xlp, Ylp, Xtep, Ytep, mask, t_real = pad_cohort(
+                    S, W0, Xl, Yl, dataset["Xte"], dataset["Yte"], bucket)
+                arrays = (Sp, W0p, Xlp, Ylp, Xtep, Ytep)
+                if self.depth == "adaptive":
+                    m = int(np.asarray(dataset["Xtr"]).shape[1])
+                    if m < self.cfg.probe_size:
+                        raise ValueError(
+                            f"adaptive serving needs probe_size="
+                            f"{self.cfg.probe_size} training rows per agent "
+                            f"for the convergence probe, got {m} — probe "
+                            "rows must be shape-constant per bucket "
+                            "executable")
+                    Xp, Yp = U.probe_batch(batch, cfg_r)
+                    arrays = arrays + pad_probe(Xp, Yp, bucket)
+            fut = ServeFuture()
+            req = _Request(
+                bucket=bucket, arrays=arrays,
+                mask=mask, t_real=t_real, n_real=n, rows_real=t, future=fut,
+                t_submit=t_submit, rid=rid,
+                deadline_ticks=(None if deadline_ticks is None
+                                else int(deadline_ticks)))
+            with self._lock:
+                self._queue.append(req)
         return fut
 
     def pending(self) -> int:
@@ -292,55 +305,67 @@ class FederationServer:
         completed (0 on an empty queue).  Bucket selection and admission
         run under the server lock (an async driver may tick while
         submits keep landing); the solve itself does not."""
-        with self._lock:
-            if not self._queue:
-                return 0
-            bucket = self._select_bucket()
-            admitted, rest = [], deque()
-            while self._queue:
-                r = self._queue.popleft()
-                if r.bucket == bucket and len(admitted) < self.max_batch:
-                    admitted.append(r)
-                else:
-                    r.ticks_waited += 1
-                    rest.append(r)
-            self._queue = rest
-        arrays, mask, t_real = zip(*[(r.arrays, r.mask, r.t_real)
-                                     for r in admitted])
-        empty, e_mask, e_t = self._empty_slot(bucket)
-        n_pad_slots = self.max_batch - len(admitted)
-        arrays = list(arrays) + [empty] * n_pad_slots
-        mask = list(mask) + [e_mask] * n_pad_slots
-        t_real = list(t_real) + [e_t] * n_pad_slots
-        stacked = [np.stack([a[i] for a in arrays])
-                   for i in range(len(arrays[0]))]
-        mask = np.stack(mask)
-        t_real = np.asarray(t_real, np.float32)
-        solve = self._solver(bucket)
-        t0 = time.perf_counter()
-        out = solve(stacked[0], self.theta, *stacked[1:], mask, t_real)
-        jax.block_until_ready(out)
-        wall = time.perf_counter() - t0
-        now = time.perf_counter()
-        lats = []
-        for i, r in enumerate(admitted):
-            res = {k: np.asarray(v[i]) for k, v in out.items()}
-            res["W"] = res["W"][:r.n_real]
-            lat = now - r.t_submit
-            r.future._set(res, lat)
-            lats.append(lat)
-        useful = sum(r.n_real * r.rows_real for r in admitted)
-        padded = self.max_batch * int(bucket.n_agents) * int(bucket.rows)
-        kw = {}
-        if self.depth == "adaptive":
-            depths = [int(np.asarray(out["depth"])[i])
-                      for i in range(len(admitted))]
-            kw = {"depths": depths,
-                  "layers_run": max(depths, default=0),
-                  "n_layers": self.cfg.n_layers}
-        self.metrics.record_tick(bucket, len(admitted), self.max_batch,
-                                 useful, padded, lats, wall, **kw)
-        return len(admitted)
+        with spans.span("serve.tick") as tick_span:
+            with spans.span("serve.tick.admit"), self._lock:
+                if not self._queue:
+                    return 0
+                bucket = self._select_bucket()
+                admitted, rest = [], deque()
+                while self._queue:
+                    r = self._queue.popleft()
+                    if (r.bucket == bucket
+                            and len(admitted) < self.max_batch):
+                        admitted.append(r)
+                    else:
+                        r.ticks_waited += 1
+                        rest.append(r)
+                self._queue = rest
+            tick_span.set(reqs=[r.rid for r in admitted],
+                          bucket=tuple(bucket))
+            with spans.span("serve.tick.stack"):
+                arrays, mask, t_real = zip(*[(r.arrays, r.mask, r.t_real)
+                                             for r in admitted])
+                empty, e_mask, e_t = self._empty_slot(bucket)
+                n_pad_slots = self.max_batch - len(admitted)
+                arrays = list(arrays) + [empty] * n_pad_slots
+                mask = list(mask) + [e_mask] * n_pad_slots
+                t_real = list(t_real) + [e_t] * n_pad_slots
+                stacked = [np.stack([a[i] for a in arrays])
+                           for i in range(len(arrays[0]))]
+                mask = np.stack(mask)
+                t_real = np.asarray(t_real, np.float32)
+            solve = self._solver(bucket)
+            bytes_in = sum(a.nbytes for a in stacked) + mask.nbytes \
+                + t_real.nbytes
+            t0 = time.perf_counter()
+            with spans.span("serve.tick.call", bytes_in=bytes_in):
+                out = solve(stacked[0], self.theta, *stacked[1:], mask,
+                            t_real)
+            with spans.span("serve.tick.wait"):
+                jax.block_until_ready(out)
+            now = time.perf_counter()
+            wall = now - t0
+            lats = []
+            with spans.span("serve.tick.unpack"):
+                for i, r in enumerate(admitted):
+                    res = {k: np.asarray(v[i]) for k, v in out.items()}
+                    res["W"] = res["W"][:r.n_real]
+                    lat = now - r.t_submit
+                    r.future._set(res, lat)
+                    lats.append(lat)
+            useful = sum(r.n_real * r.rows_real for r in admitted)
+            padded = self.max_batch * int(bucket.n_agents) * int(bucket.rows)
+            kw = {}
+            if self.depth == "adaptive":
+                depths = [int(np.asarray(out["depth"])[i])
+                          for i in range(len(admitted))]
+                kw = {"depths": depths,
+                      "layers_run": max(depths, default=0),
+                      "n_layers": self.cfg.n_layers}
+            self.metrics.record_tick(bucket, len(admitted), self.max_batch,
+                                     useful, padded, lats, wall, done_at=now,
+                                     **kw)
+            return len(admitted)
 
     def drain(self) -> int:
         """Tick until the queue is empty; returns requests completed."""

@@ -59,14 +59,15 @@ def _masked_scores(task):
     """Padded-cohort loss/metric: the task's ``padded_local_*``
     row-corrections per agent, averaged over REAL agents only."""
     def masked_scores(W, Xte, Yte, mask, t_real):
-        per_loss = jax.vmap(task.padded_local_loss,
-                            in_axes=(0, 0, 0, None))(W, Xte, Yte, t_real)
-        per_met = jax.vmap(task.padded_local_metric,
-                           in_axes=(0, 0, 0, None))(W, Xte, Yte, t_real)
-        denom = jnp.maximum(jnp.sum(mask), 1.0)
-        loss = jnp.sum(jnp.where(mask, per_loss, 0.0)) / denom
-        met = jnp.sum(jnp.where(mask, per_met, 0.0)) / denom
-        return loss, met
+        with jax.named_scope("surf/loss"):
+            per_loss = jax.vmap(task.padded_local_loss,
+                                in_axes=(0, 0, 0, None))(W, Xte, Yte, t_real)
+            per_met = jax.vmap(task.padded_local_metric,
+                               in_axes=(0, 0, 0, None))(W, Xte, Yte, t_real)
+            denom = jnp.maximum(jnp.sum(mask), 1.0)
+            loss = jnp.sum(jnp.where(mask, per_loss, 0.0)) / denom
+            met = jnp.sum(jnp.where(mask, per_met, 0.0)) / denom
+            return loss, met
 
     return masked_scores
 
