@@ -221,19 +221,19 @@ def new_federations(cfg, n):
     return out
 
 
-def served_sharding(server, bucket):
+def served_sharding(server, bucket, request):
     """Sharding of the per-request outputs of the executable ``tick()``
     runs for ``bucket``: the server's cached bucket solver (a cache hit,
-    checked), run on an all-masked batch as ``warm()`` runs it."""
+    checked), run as ``warm()`` runs it, on a batch assembled from
+    ``request``'s device slot under all-false masks."""
     misses = server.cache_stats()["misses"]
     solve = server._solver(bucket)
     if server.cache_stats()["misses"] != misses:
         fail(f"the server built a new executable for {bucket}")
-    empty, mask, t = server._empty_slot(bucket)
-    b = server.max_batch
-    args = [np.stack([a] * b) for a in empty]
-    out = solve(args[0], server.theta, *args[1:], np.stack([mask] * b),
-                np.full((b,), t, np.float32))
+    slot = server._slot(request["S"], request["ds"], bucket,
+                        request["seed"], 0)
+    args, mask, t_real = server._batch(bucket, [slot])
+    out = solve(args[0], server.theta, *args[1:], mask, t_real)
     return out["final_loss"].sharding
 
 
@@ -259,7 +259,7 @@ def serve_phase(clock, name, cfg, state, requests, devices, mesh=None):
         server.warm([(cfg.n_agents, cfg.test_per_agent)]), answer()))
     compile_s = clock.take()
     results, warm = timed(answer)
-    out_sharding = served_sharding(server, buckets[0])
+    out_sharding = served_sharding(server, buckets[0], requests[0])
     refs = [surf.solve_federation(cfg, state, r["S"], r["ds"],
                                   seed=r["seed"]) for r in requests]
     par, ok = parity([r["final_loss"] for r in results],

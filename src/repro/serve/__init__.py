@@ -16,8 +16,9 @@ zero at request rate.
 
 Layers: ``solver`` (the jitted request-vmapped masked forward;
 ``mesh=`` shards the request axis over devices), ``buckets`` (shape
-bucketing + provably-inert padding), ``queue`` (continuous batching +
-futures, deadline-aware admission), ``driver`` (``AsyncDriver`` — a
+bucketing + provably-inert padding, on the device), ``queue``
+(continuous batching of device-resident slots + futures,
+deadline-aware admission), ``driver`` (``AsyncDriver`` — a
 background tick thread so ``submit`` returns immediately), ``metrics``
 (throughput/latency/pad-waste/cache telemetry).  The CLI driver is
 ``repro.launch.surf_serve``.
@@ -32,14 +33,17 @@ and each ``submit`` leaves ``serve.submit`` (attribute ``req``, the
 request's id) with ``serve.submit.featurize`` and ``serve.submit.pad``
 inside it, and each ``tick`` leaves ``serve.tick`` (``reqs``, the ids it
 admitted; ``bucket``) with ``serve.tick.admit``, ``.stack``, ``.call``
-(``bytes_in``, host bytes handed to the solver, θ excluded), ``.wait``
-and ``.unpack`` inside it.  Each is a record in ``recs`` (start, end,
+(``bytes_in``, host bytes handed to the solver: the slots' masks and
+``t_real``, since request data is uploaded once, at ``submit``),
+``.wait`` and ``.unpack`` (one transfer of the outputs, then the
+per-request split) inside it.  Each is a record in ``recs`` (start, end,
 thread CPU seconds, parent) and a ``surf.*`` host event in the profile,
 beside the solver's device operations, which carry the ``surf/mix``,
 ``surf/perceptron`` and ``surf/loss`` scopes.  Without a profiler
 session nothing is recorded (``repro.utils.spans``).
 """
-from repro.serve.buckets import Bucket, BucketSpec, pad_cohort, pad_probe
+from repro.serve.buckets import (Bucket, BucketSpec, pad_cohort, pad_probe,
+                                 slot_mask)
 from repro.serve.driver import AsyncDriver
 from repro.serve.metrics import ServeMetrics
 from repro.serve.queue import FederationServer, ServeFuture
@@ -47,7 +51,7 @@ from repro.serve.solver import (SERVE_MIXES, make_bucket_solver,
                                 request_shardings, resolve_serve_mix,
                                 serve_cache_key)
 
-__all__ = ["Bucket", "BucketSpec", "pad_cohort", "pad_probe",
+__all__ = ["Bucket", "BucketSpec", "pad_cohort", "pad_probe", "slot_mask",
            "AsyncDriver", "ServeMetrics", "FederationServer",
            "ServeFuture", "SERVE_MIXES", "make_bucket_solver",
            "request_shardings", "resolve_serve_mix", "serve_cache_key"]

@@ -18,12 +18,17 @@ inert:
 
 ``pad_cohort`` runs AFTER ``core.unroll.featurize_cohort`` — W0 and the
 layer batches were drawn at the true cohort shape, so padding never
-perturbs the RNG stream.
+perturbs the RNG stream.  It pads on the device: the server runs it
+inside one jitted program per (true shape, bucket), so a request's
+padded slot never leaves the device between ``submit`` and the solve.
+Only the slot's ``mask`` and ``t_real`` (``slot_mask``) stay on the
+host.
 """
 from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax.numpy as jnp
 import numpy as np
 
 
@@ -65,38 +70,40 @@ class BucketSpec(NamedTuple):
         return out
 
 
+def _zero_pad(a, size, axis=0):
+    """``a`` zero-padded to ``size`` along ``axis``."""
+    widths = [(0, 0)] * a.ndim
+    widths[axis] = (0, size - a.shape[axis])
+    return jnp.pad(a, widths)
+
+
 def pad_cohort(S, W0, Xl, Yl, Xte, Yte, bucket: Bucket):
-    """Pad one featurized cohort to ``bucket`` shape.  Returns
-    ``(S, W0, Xl, Yl, Xte, Yte, mask, t_real)`` numpy arrays — agent
-    axis padded with zeros (and zero S rows/cols), test-row axis padded
-    with row-0 copies, ``mask`` (n_pad,) bool flagging real agents,
-    ``t_real`` the float true row count the padded-loss corrections
-    consume."""
-    S, W0 = np.asarray(S), np.asarray(W0)
-    Xl, Yl = np.asarray(Xl), np.asarray(Yl)
-    Xte, Yte = np.asarray(Xte), np.asarray(Yte)
+    """Pad one featurized cohort to ``bucket`` shape.  Returns ``(S, W0,
+    Xl, Yl, Xte, Yte)`` as jax arrays — agent axis padded with zeros (and
+    zero S rows/cols), test-row axis padded with row-0 copies.  Runs
+    eagerly or under ``jit``; ``slot_mask`` gives the host side."""
     n, t = S.shape[0], Xte.shape[1]
     npad, tpad = int(bucket.n_agents), int(bucket.rows)
     if n > npad or t > tpad:
         raise ValueError(f"cohort (n={n}, t={t}) does not fit bucket "
                          f"{bucket}")
-    Sp = np.zeros((npad, npad), S.dtype)
-    Sp[:n, :n] = S
-    W0p = np.zeros((npad,) + W0.shape[1:], W0.dtype)
-    W0p[:n] = W0
-    Xlp = np.zeros((Xl.shape[0], npad) + Xl.shape[2:], Xl.dtype)
-    Xlp[:, :n] = Xl
-    Ylp = np.zeros((Yl.shape[0], npad) + Yl.shape[2:], Yl.dtype)
-    Ylp[:, :n] = Yl
-    Xtep = np.zeros((npad, tpad) + Xte.shape[2:], Xte.dtype)
-    Xtep[:n, :t] = Xte
-    Xtep[:n, t:] = Xte[:, :1]                 # row-0 copies (see module doc)
-    Ytep = np.zeros((npad, tpad) + Yte.shape[2:], Yte.dtype)
-    Ytep[:n, :t] = Yte
-    Ytep[:n, t:] = Yte[:, :1]
-    mask = np.zeros(npad, bool)
+
+    def rows(a):                              # row-0 copies (module doc)
+        return jnp.concatenate(
+            [a, jnp.repeat(a[:, :1], tpad - t, axis=1)], axis=1)
+
+    return (_zero_pad(_zero_pad(S, npad), npad, 1), _zero_pad(W0, npad),
+            _zero_pad(Xl, npad, 1), _zero_pad(Yl, npad, 1),
+            _zero_pad(rows(Xte), npad), _zero_pad(rows(Yte), npad))
+
+
+def slot_mask(n: int, t: int, bucket: Bucket):
+    """The host side of a padded slot: ``mask`` (n_pad,) bool flagging
+    the ``n`` real agents, and ``t_real``, the float true row count the
+    padded-loss corrections consume."""
+    mask = np.zeros(int(bucket.n_agents), bool)
     mask[:n] = True
-    return Sp, W0p, Xlp, Ylp, Xtep, Ytep, mask, np.float32(t)
+    return mask, np.float32(t)
 
 
 def pad_probe(Xp, Yp, bucket: Bucket):
@@ -104,12 +111,7 @@ def pad_probe(Xp, Yp, bucket: Bucket):
     ``bucket``'s agent count.  Probe ROWS are a config constant
     (``cfg.probe_size``) so only the agent axis pads — with zeros, which
     ``task.masked_grad_norm`` zeroes out of the certificate exactly."""
-    Xp, Yp = np.asarray(Xp), np.asarray(Yp)
     n, npad = Xp.shape[0], int(bucket.n_agents)
     if n > npad:
         raise ValueError(f"probe (n={n}) does not fit bucket {bucket}")
-    Xpp = np.zeros((npad,) + Xp.shape[1:], Xp.dtype)
-    Xpp[:n] = Xp
-    Ypp = np.zeros((npad,) + Yp.shape[1:], Yp.dtype)
-    Ypp[:n] = Yp
-    return Xpp, Ypp
+    return _zero_pad(Xp, npad), _zero_pad(Yp, npad)

@@ -1,13 +1,18 @@
 """Continuous-batching federation server.
 
 ``FederationServer`` turns the bucketed request-vmapped solver into a
-request/response loop: ``submit()`` featurizes ONE new federation (its
-mixing matrix + dataset) at its true shape, pads it into its shape
-bucket and enqueues it; ``tick()`` admits up to ``max_batch``
-bucket-compatible requests FIFO-first, stacks them into the bucket's
-fixed ``(B, n_pad, ...)`` batch (empty slots are masked out, so the
-executable never sees a new batch size) and solves them in one jitted
-call, scattering per-request results to their futures.
+request/response loop: ``submit()`` uploads ONE new federation (its
+mixing matrix + dataset), featurizes it at its true shape and pads it
+into its shape bucket on the device (``_sample_and_pad``), and enqueues
+the padded slot without waiting for the chip; ``tick()`` admits up to
+``max_batch`` bucket-compatible requests FIFO-first, stacks their device
+slots into the bucket's fixed ``(B, n_pad, ...)`` batch with one jitted
+``assemble`` program (empty slots repeat the first slot and are masked
+out, so the executable never sees a new batch size), solves them in
+one jitted call and fetches the outputs in one transfer, splitting them
+into per-request host results for the futures.  A request's data thus
+crosses the host link once, at ``submit``; only each slot's small
+``mask`` and ``t_real`` are host arrays.
 
 The admission rule favors batch fullness without starving rare shapes:
 a tick serves the FULLEST bucket in the queue (ties broken by FIFO head
@@ -20,7 +25,9 @@ shape could otherwise monopolize admission.  A request submitted with
 the deadline — latency-sensitive requests cut ahead of fuller buckets.
 
 ``mesh=`` shards the request axis of every bucket executable over the
-mesh's agent-role axis (``solver.request_shardings``) — serving is
+mesh's agent-role axis (``solver.request_shardings``; ``assemble`` lays
+the batch out that way, so the solver gets its blocks without a
+reshard) — serving is
 embarrassingly parallel, so a batch of B requests splits over devices
 with zero collectives.  ``serve.AsyncDriver`` wraps the server in a
 background tick thread (``submit`` returns immediately, ticks fire at a
@@ -34,11 +41,14 @@ padded convergence-probe split, results gain a realized ``depth``, and
 
 Everything expensive is cached: one executable per (bucket, B, mix,
 task) in a per-server ``BoundedLRU`` (registered as "serve-buckets" for
-``repro.clear_caches()``), warmed ahead of traffic with ``warm()``.
+``repro.clear_caches()``), beside one pad program per (true shape,
+bucket) and one ``assemble`` program per bucket shape, all warmed ahead
+of traffic with ``warm()``.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import itertools
 import threading
 import time
@@ -51,9 +61,10 @@ import numpy as np
 from repro.configs.base import SURFConfig
 from repro.core import unroll as U
 from repro.core.tasks import resolve_task
-from repro.serve.buckets import BucketSpec, pad_cohort, pad_probe
+from repro.serve.buckets import BucketSpec, pad_cohort, pad_probe, slot_mask
 from repro.serve.metrics import ServeMetrics
-from repro.serve.solver import make_bucket_solver, resolve_serve_mix
+from repro.serve.solver import (make_bucket_solver, request_shardings,
+                                resolve_serve_mix)
 from repro.utils import spans
 from repro.utils.cache import BoundedLRU
 
@@ -87,7 +98,9 @@ class ServeFuture:
 class _Request:
     bucket: object
     arrays: tuple                        # padded (S, W0, Xl, Yl, Xte, Yte)
-    mask: np.ndarray                     # (+ Xp, Yp when depth="adaptive")
+    #                                      (+ Xp, Yp when depth="adaptive"),
+    #                                      on the device until admitted
+    mask: np.ndarray
     t_real: np.float32
     n_real: int
     rows_real: int
@@ -131,7 +144,6 @@ class FederationServer:
             # axis must split evenly over the mesh (ragged TRAFFIC is
             # fine — masked empty slots — but the bucket batch shape
             # is fixed)
-            from repro.serve.solver import request_shardings
             request_shardings(mesh, int(max_batch), depth)
         self.depth = depth
         self.max_wait_ticks = int(max_wait_ticks)
@@ -144,6 +156,12 @@ class FederationServer:
         self.max_batch = int(max_batch)
         self.mesh = mesh
         self._cache = BoundedLRU(maxsize=max_buckets, name="serve-buckets")
+        # one program per bucket shape stacks B device slots into the
+        # solver's (B, ...) arguments, laid out as the solver takes them
+        self._assemble = jax.jit(_stack_slots, **(
+            {} if mesh is None else
+            {"out_shardings": request_shardings(mesh, self.max_batch,
+                                                depth)[1]}))
         self.metrics = ServeMetrics(cache=self._cache)
         self._queue = deque()
         self._ids = itertools.count()
@@ -162,8 +180,11 @@ class FederationServer:
         ``evaluate_surf(..., seed=seed)`` stream for dataset index
         ``q``, which is what makes serve results parity-testable
         against single-cohort evaluation.  Featurization (W0 + layer
-        mini-batches) happens NOW at the true cohort shape; padding
-        follows, so it never perturbs the draw.
+        mini-batches) is dispatched NOW at the true cohort shape;
+        padding follows on the device, so it never perturbs the draw.
+        ``submit`` validates on the host and returns without waiting
+        for the chip: the queued request holds its padded slot as
+        device arrays.
 
         ``deadline_ticks``: optional admission deadline — the request
         should be admitted within that many ticks of entering the
@@ -184,37 +205,22 @@ class FederationServer:
             missing = [k for k in _REQUIRED if k not in dataset]
             if missing:
                 raise ValueError(f"dataset missing keys {missing}")
-            for k in _REQUIRED:
-                if np.asarray(dataset[k]).shape[0] != n:
+            shapes = {k: np.shape(dataset[k]) for k in _REQUIRED}
+            for k, shape in shapes.items():
+                if shape[0] != n:
                     raise ValueError(
-                        f"dataset[{k!r}] leads with "
-                        f"{np.asarray(dataset[k]).shape[0]} agents but S is "
-                        f"{n}x{n}")
-            cfg_r = dataclasses.replace(self.cfg, n_agents=n)
-            with spans.span("serve.submit.featurize"):
-                key = jax.random.fold_in(
-                    jax.random.PRNGKey(1000 + int(seed)), int(q))
-                batch = {k: jnp.asarray(np.asarray(dataset[k]))
-                         for k in _REQUIRED}
-                W0, Xl, Yl = (np.asarray(a) for a in U.featurize_cohort(
-                    key, batch, cfg_r, task=self.task))
-            with spans.span("serve.submit.pad"):
-                t = int(np.asarray(dataset["Xte"]).shape[1])
-                bucket = self.buckets.bucket_for(n, t)
-                Sp, W0p, Xlp, Ylp, Xtep, Ytep, mask, t_real = pad_cohort(
-                    S, W0, Xl, Yl, dataset["Xte"], dataset["Yte"], bucket)
-                arrays = (Sp, W0p, Xlp, Ylp, Xtep, Ytep)
-                if self.depth == "adaptive":
-                    m = int(np.asarray(dataset["Xtr"]).shape[1])
-                    if m < self.cfg.probe_size:
-                        raise ValueError(
-                            f"adaptive serving needs probe_size="
-                            f"{self.cfg.probe_size} training rows per agent "
-                            f"for the convergence probe, got {m} — probe "
-                            "rows must be shape-constant per bucket "
-                            "executable")
-                    Xp, Yp = U.probe_batch(batch, cfg_r)
-                    arrays = arrays + pad_probe(Xp, Yp, bucket)
+                        f"dataset[{k!r}] leads with {shape[0]} agents but "
+                        f"S is {n}x{n}")
+            m, t = shapes["Xtr"][1], shapes["Xte"][1]
+            if self.depth == "adaptive" and m < self.cfg.probe_size:
+                raise ValueError(
+                    f"adaptive serving needs probe_size="
+                    f"{self.cfg.probe_size} training rows per agent for "
+                    f"the convergence probe, got {m} — probe rows must be "
+                    "shape-constant per bucket executable")
+            bucket = self.buckets.bucket_for(n, t)
+            arrays = self._slot(S, dataset, bucket, seed, q)
+            mask, t_real = slot_mask(n, t, bucket)
             fut = ServeFuture()
             req = _Request(
                 bucket=bucket, arrays=arrays,
@@ -225,6 +231,27 @@ class FederationServer:
             with self._lock:
                 self._queue.append(req)
         return fut
+
+    def _slot(self, S, dataset, bucket, seed, q):
+        """A request's padded device slot: the dataset uploaded once,
+        ``featurize_cohort``'s draws at the true shape (so they are the
+        ones ``evaluate_surf`` makes), then padding, with the mini-batch
+        draw and the padding in one program (``_sample_and_pad``).  Only
+        dispatches: nothing here waits for the chip."""
+        cfg_r = dataclasses.replace(self.cfg, n_agents=S.shape[0])
+        with spans.span("serve.submit.featurize"):
+            batch = {k: jnp.asarray(dataset[k]) for k in _REQUIRED}
+            # featurize_cohort's stream, split: W0 is drawn eagerly (under
+            # jit its draw can round differently, by a fused multiply-add),
+            # the mini-batches inside _sample_and_pad (exact under jit)
+            key = jax.random.fold_in(
+                jax.random.PRNGKey(1000 + int(seed)), int(q))
+            kw, kb = jax.random.split(key)
+            W0 = U.sample_w0(kw, cfg_r, task=self.task)
+        with spans.span("serve.submit.pad"):
+            return _sample_and_pad(kb, S, W0, batch, cfg=cfg_r,
+                                   bucket=bucket,
+                                   probe=self.depth == "adaptive")
 
     def pending(self) -> int:
         """Requests currently queued (admitted-but-unsolved is never
@@ -240,26 +267,25 @@ class FederationServer:
                                   cache=self._cache, depth=self.depth,
                                   mesh=self.mesh)
 
-    def _empty_slot(self, bucket):
-        """All-zero, all-masked batch slot — t_real = t_pad keeps the
-        padded-loss corrections on their identity branch.  The all-false
-        mask also starts adaptive slots INACTIVE (depth 0, no layer
-        work charged to them)."""
-        d, b = self.task.dim, self.cfg.batch_per_agent
-        F, L = self.task.feat_dim, self.cfg.n_layers
-        n, t = int(bucket.n_agents), int(bucket.rows)
-        ydt = np.dtype(self.task.label_dtype)
-        arrays = (np.zeros((n, n), np.float32),
-                  np.zeros((n, d), np.float32),
-                  np.zeros((L, n, b, F), np.float32),
-                  np.zeros((L, n, b), ydt),
-                  np.zeros((n, t, F), np.float32),
-                  np.zeros((n, t), ydt))
-        if self.depth == "adaptive":
-            p = int(self.cfg.probe_size)
-            arrays = arrays + (np.zeros((n, p, F), np.float32),
-                               np.zeros((n, p), ydt))
-        return arrays, np.zeros(n, bool), np.float32(t)
+    def _batch(self, bucket, slots, masks=(), t_reals=()):
+        """The solver's arguments for the device ``slots`` (one or more)
+        of ``bucket``, the first ``len(masks)`` of them admitted: the
+        ``assemble`` program stacks them into ``max_batch`` slots, each
+        missing one a repeat of the first slot, and every slot past the
+        admitted ones gets an all-false mask and ``t_real = t_pad`` —
+        masked agents never reach a result (the padded-loss corrections
+        stay on their identity branch; adaptive slots start INACTIVE,
+        depth 0), and an empty slot's result is never read — so empty
+        slots cost no device memory of their own.  ``mask`` (B, n_pad)
+        and ``t_real`` (B,) are host arrays."""
+        B = self.max_batch
+        stacked = self._assemble(list(slots)
+                                 + [slots[0]] * (B - len(slots)))
+        e_mask, e_t = slot_mask(0, int(bucket.rows), bucket)
+        empty = B - len(masks)
+        mask = np.stack(list(masks) + [e_mask] * empty)
+        t_real = np.array(list(t_reals) + [e_t] * empty, np.float32)
+        return stacked, mask, t_real
 
     def _select_bucket(self):
         """The tick's bucket, by the deadline-then-aging admission
@@ -323,33 +349,29 @@ class FederationServer:
             tick_span.set(reqs=[r.rid for r in admitted],
                           bucket=tuple(bucket))
             with spans.span("serve.tick.stack"):
-                arrays, mask, t_real = zip(*[(r.arrays, r.mask, r.t_real)
-                                             for r in admitted])
-                empty, e_mask, e_t = self._empty_slot(bucket)
-                n_pad_slots = self.max_batch - len(admitted)
-                arrays = list(arrays) + [empty] * n_pad_slots
-                mask = list(mask) + [e_mask] * n_pad_slots
-                t_real = list(t_real) + [e_t] * n_pad_slots
-                stacked = [np.stack([a[i] for a in arrays])
-                           for i in range(len(arrays[0]))]
-                mask = np.stack(mask)
-                t_real = np.asarray(t_real, np.float32)
+                stacked, mask, t_real = self._batch(
+                    bucket, *zip(*[(r.arrays, r.mask, r.t_real)
+                                   for r in admitted]))
+                for r in admitted:      # free each slot once stacked
+                    r.arrays = None
             solve = self._solver(bucket)
-            bytes_in = sum(a.nbytes for a in stacked) + mask.nbytes \
-                + t_real.nbytes
             t0 = time.perf_counter()
-            with spans.span("serve.tick.call", bytes_in=bytes_in):
+            with spans.span("serve.tick.call",
+                            bytes_in=mask.nbytes + t_real.nbytes):
                 out = solve(stacked[0], self.theta, *stacked[1:], mask,
                             t_real)
+            del stacked
             with spans.span("serve.tick.wait"):
                 jax.block_until_ready(out)
             now = time.perf_counter()
             wall = now - t0
             lats = []
             with spans.span("serve.tick.unpack"):
+                host = jax.device_get(out)
                 for i, r in enumerate(admitted):
-                    res = {k: np.asarray(v[i]) for k, v in out.items()}
-                    res["W"] = res["W"][:r.n_real]
+                    res = {k: np.array(v[i]) for k, v in host.items()
+                           if k != "W"}
+                    res["W"] = np.array(host["W"][i, :r.n_real])
                     lat = now - r.t_submit
                     r.future._set(res, lat)
                     lats.append(lat)
@@ -357,8 +379,7 @@ class FederationServer:
             padded = self.max_batch * int(bucket.n_agents) * int(bucket.rows)
             kw = {}
             if self.depth == "adaptive":
-                depths = [int(np.asarray(out["depth"])[i])
-                          for i in range(len(admitted))]
+                depths = [int(d) for d in host["depth"][:len(admitted)]]
                 kw = {"depths": depths,
                       "layers_run": max(depths, default=0),
                       "n_layers": self.cfg.n_layers}
@@ -377,23 +398,55 @@ class FederationServer:
     # ------------------------------------------------------------- warm
     def warm(self, cohorts) -> list:
         """Compile ahead of traffic: ``cohorts`` is an iterable of
-        (n_agents, test_rows) pairs; each distinct bucket they map to
-        gets its executable built and run once on an all-masked zero
-        batch (identical jit signature to real traffic — exactly ONE
-        body trace per bucket, which ``launch.surf_serve`` asserts).
-        Returns the warmed buckets."""
-        warmed = self.buckets.buckets_for(cohorts)
-        for bucket in warmed:
-            solve = self._solver(bucket)
-            empty, e_mask, e_t = self._empty_slot(bucket)
-            stacked = [np.stack([empty[i]] * self.max_batch)
-                       for i in range(len(empty))]
-            mask = np.stack([e_mask] * self.max_batch)
-            t_real = np.full((self.max_batch,), e_t, np.float32)
-            out = solve(stacked[0], self.theta, *stacked[1:], mask, t_real)
-            jax.block_until_ready(out)
-        return warmed
+        (n_agents, test_rows) pairs.  Each pair gets its featurization
+        and pad program built by padding an all-zero request of that
+        true shape (``cfg.train_per_agent`` training rows); each distinct
+        bucket they map to gets its ``assemble`` program and executable,
+        run once on a batch of such a slot under all-false masks, through
+        the same path as a tick (identical jit signatures to real traffic
+        — exactly ONE solver body trace per bucket, which
+        ``launch.surf_serve`` asserts).  Returns the warmed buckets."""
+        ydt = self.task.label_dtype
+        F, m = self.task.feat_dim, int(self.cfg.train_per_agent)
+        slots = {}
+        for n, t in cohorts:
+            zeros = {"Xtr": np.zeros((n, m, F), np.float32),
+                     "Ytr": np.zeros((n, m), ydt),
+                     "Xte": np.zeros((n, t, F), np.float32),
+                     "Yte": np.zeros((n, t), ydt)}
+            bucket = self.buckets.bucket_for(n, t)
+            slot = self._slot(np.zeros((n, n), np.float32), zeros, bucket,
+                              0, 0)
+            slots.setdefault(bucket, slot)
+        for bucket, slot in slots.items():
+            stacked, mask, t_real = self._batch(bucket, [slot])
+            out = self._solver(bucket)(stacked[0], self.theta, *stacked[1:],
+                                       mask, t_real)
+            jax.device_get(out)
+        return list(slots)
 
     def cache_stats(self) -> dict:
         """Stats of this server's bucket-executable cache."""
         return self._cache.stats()
+
+
+def _stack_slots(slots):
+    """B padded slots (tuples of device arrays, one per request) → the
+    tuple of (B, ...) stacked solver arguments; jitted per server as its
+    ``assemble`` program."""
+    return tuple(jnp.stack(a) for a in zip(*slots))
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "bucket", "probe"))
+def _sample_and_pad(kb, S, W0, batch, *, cfg, bucket, probe):
+    """One request's padded device slot, one program per (true shape,
+    bucket): the layer mini-batches drawn from ``kb`` at the true shape
+    (``unroll.sample_layer_batches``: integer draws and a one-hot
+    contraction at HIGHEST precision, so bit-identical to the eager
+    draw), then ``pad_cohort`` and, with ``probe``, ``pad_probe`` of the
+    convergence-probe split."""
+    Xl, Yl = U.sample_layer_batches(kb, batch["Xtr"], batch["Ytr"], cfg)
+    slot = pad_cohort(S, W0, Xl, Yl, batch["Xte"], batch["Yte"], bucket)
+    if probe:
+        slot += pad_probe(*U.probe_batch(batch, cfg), bucket)
+    return slot

@@ -227,10 +227,10 @@ def test_junk_in_probe_pad_region_is_inert(trained):
                            max_batch=4, depth="adaptive")
     fut = srv.submit(S, ds, seed=1)
     req = srv._queue[0]
-    arrs = [a.copy() for a in req.arrays]
-    arrs[1][6:] = 1e6                       # W0 pad rows
-    arrs[2][:, 6:] = -3e5                   # layer-batch pad rows
-    arrs[6][6:] = 4e5                       # probe X pad rows
+    arrs = list(req.arrays)
+    arrs[1] = arrs[1].at[6:].set(1e6)       # W0 pad rows
+    arrs[2] = arrs[2].at[:, 6:].set(-3e5)   # layer-batch pad rows
+    arrs[6] = arrs[6].at[6:].set(4e5)       # probe X pad rows
     req.arrays = tuple(arrs)
     srv.drain()
     ref = surf.solve_federation(cfg_r, state, S, ds, seed=1,

@@ -10,6 +10,7 @@ claim under test.
 """
 import dataclasses
 
+import jax
 import numpy as np
 import pytest
 
@@ -17,11 +18,12 @@ from repro import cache_stats, clear_caches
 from repro import engine as E
 from repro.configs.surf_paper import SMOKE, SPARSE_SMOKE
 from repro.core import surf
+from repro.core import unroll as U
 from repro.core.tasks import resolve_task, sparse_recovery_task
 from repro.data import synthetic
 from repro.serve import (AsyncDriver, Bucket, BucketSpec,
                          FederationServer, ServeMetrics, pad_cohort,
-                         serve_cache_key)
+                         pad_probe, serve_cache_key, slot_mask)
 from repro.utils.cache import BoundedLRU
 
 CFG = SMOKE
@@ -70,8 +72,9 @@ def test_pad_cohort_geometry():
     Xl = np.ones((CFG.n_layers, n, CFG.batch_per_agent, CFG.feature_dim),
                  np.float32)
     Yl = np.ones((CFG.n_layers, n, CFG.batch_per_agent), np.int32)
-    Sp, W0p, Xlp, Ylp, Xtep, Ytep, mask, t_real = pad_cohort(
-        S, W0, Xl, Yl, ds["Xte"], ds["Yte"], Bucket(8, 8))
+    Sp, W0p, Xlp, Ylp, Xtep, Ytep = (np.asarray(a) for a in pad_cohort(
+        S, W0, Xl, Yl, ds["Xte"], ds["Yte"], Bucket(8, 8)))
+    mask, t_real = slot_mask(6, 4, Bucket(8, 8))
     assert Sp.shape == (8, 8) and not Sp[6:].any() and not Sp[:, 6:].any()
     assert not W0p[6:].any() and not Xlp[:, 6:].any()
     # padded test rows are row-0 copies for real agents, zero for padded
@@ -141,10 +144,10 @@ def test_junk_in_pad_region_is_inert(trained):
     srv = _server(state.theta)
     fut = srv.submit(S, ds, seed=1)
     req = srv._queue[0]
-    Sp, W0p, Xlp, Ylp, Xtep, Ytep = (a.copy() for a in req.arrays)
-    W0p[6:] = 1e6          # junk where the mask says "padded agent"
-    Xlp[:, 6:] = -3e5
-    Xtep[6:] = 7e4
+    Sp, W0p, Xlp, Ylp, Xtep, Ytep = req.arrays
+    W0p = W0p.at[6:].set(1e6)   # junk where the mask says "padded agent"
+    Xlp = Xlp.at[:, 6:].set(-3e5)
+    Xtep = Xtep.at[6:].set(7e4)
     req.arrays = (Sp, W0p, Xlp, Ylp, Xtep, Ytep)
     srv.drain()
     ref = surf.solve_federation(cfg_r, state, S, ds, seed=1)
@@ -230,6 +233,110 @@ def test_trace_count_one_per_warm_bucket_zero_at_request_rate(trained):
         srv.submit(S, ds, seed=i)
     srv.drain()
     assert E.TRACE_COUNTS["serve"] - base == 2     # zero replay traces
+
+
+# --------------------------------------------- device-resident slots
+COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                  "/jax/core/compile/backend_compile_duration")
+
+
+@pytest.fixture(scope="module")
+def compiles():
+    """A running count of JAX trace and compile events in this process."""
+    seen = [0]
+
+    def on(event, duration, **_):
+        if event in COMPILE_EVENTS:
+            seen[0] += 1
+    jax.monitoring.register_event_duration_secs_listener(on)
+    return seen
+
+
+@pytest.mark.parametrize("depth", ["fixed", "adaptive"])
+def test_queued_request_holds_device_slot_of_bucket_shape(trained, depth):
+    """``submit`` queues the padded slot as device arrays of the bucket's
+    shapes, bitwise ``pad_cohort`` of the eager ``featurize_cohort``."""
+    state, _ = trained
+    srv = _server(state.theta, depth=depth)
+    cfg_r, S, ds = _cohort(6, 4, seed=95)
+    srv.submit(S, ds, seed=3, q=1)
+    req = srv._queue[0]
+    assert all(isinstance(a, jax.Array) for a in req.arrays)
+    d, L = resolve_task(CFG).dim, CFG.n_layers
+    b, F, p = CFG.batch_per_agent, CFG.feature_dim, CFG.probe_size
+    shapes = [(8, 8), (8, d), (L, 8, b, F), (L, 8, b), (8, 4, F), (8, 4)]
+    if depth == "adaptive":
+        shapes += [(8, p, F), (8, p)]
+    assert [a.shape for a in req.arrays] == shapes
+    assert isinstance(req.mask, np.ndarray)
+    assert req.mask.tolist() == [True] * 6 + [False] * 2
+    key = jax.random.fold_in(jax.random.PRNGKey(1003), 1)
+    batch = {k: jax.numpy.asarray(v) for k, v in ds.items()}
+    ref = pad_cohort(S, *U.featurize_cohort(key, batch, cfg_r),
+                     batch["Xte"], batch["Yte"], Bucket(8, 4))
+    if depth == "adaptive":
+        ref += pad_probe(*U.probe_batch(batch, cfg_r), Bucket(8, 4))
+    for got, want in zip(req.arrays, ref):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("k", [3, 8])
+def test_tick_matches_host_stacked_batch_bitwise(trained, k):
+    """A tick's device-assembled batch solves to exactly what the same
+    executable returns on the host-stacked numpy batch of the same
+    slots, zero-filled past the ``k`` admitted requests: the empty slots'
+    repeats of the first slot reach no result."""
+    state, _ = trained
+    srv = _server(state.theta, max_batch=8)
+    futs = [srv.submit(*_cohort([6, 8][i % 2], 4, seed=100 + i)[1:],
+                       seed=i) for i in range(k)]
+    reqs = list(srv._queue)
+    slots = [[np.asarray(a) for a in r.arrays] for r in reqs]
+    slots += [[np.zeros_like(a) for a in slots[0]]] * (8 - k)
+    args = [np.stack(a) for a in zip(*slots)]
+    mask = np.stack([r.mask for r in reqs]
+                    + [np.zeros(8, bool)] * (8 - k))
+    t_real = np.array([r.t_real for r in reqs] + [4.0] * (8 - k),
+                      np.float32)
+    ref = jax.device_get(srv._solver(Bucket(8, 4))(
+        args[0], state.theta, *args[1:], mask, t_real))
+    assert srv.tick() == k
+    for i, (r, f) in enumerate(zip(reqs, futs)):
+        res = f.result()
+        np.testing.assert_array_equal(res["W"], ref["W"][i, :r.n_real])
+        for key in ("final_loss", "final_acc", "loss_per_layer",
+                    "acc_per_layer"):
+            np.testing.assert_array_equal(res[key], ref[key][i])
+
+
+def test_results_do_not_share_memory(trained):
+    state, _ = trained
+    srv = _server(state.theta)
+    futs = [srv.submit(*_cohort(6, 4, seed=110 + i)[1:], seed=i)
+            for i in range(2)]
+    srv.tick()
+    a, b = (f.result() for f in futs)
+    for key in a:
+        assert a[key].flags.writeable
+        assert not np.shares_memory(a[key], b[key]), key
+
+
+def test_warm_then_one_request_leaves_ticks_compile_free(trained,
+                                                         compiles):
+    """After ``warm()`` and one warm request, further requests of the
+    same shape trace and compile nothing, at submit or at the tick."""
+    state, _ = trained
+    cohorts = [_cohort(6, 4, seed=120 + i)[1:] for i in range(4)]
+    srv = _server(state.theta)
+    srv.warm([(6, 4)])
+    srv.submit(*cohorts[0], seed=0)
+    assert srv.tick() == 1
+    seen, traces = compiles[0], E.TRACE_COUNTS["serve"]
+    for i, (S, ds) in enumerate(cohorts[1:]):
+        srv.submit(S, ds, seed=1 + i)
+    assert srv.tick() == 3
+    assert compiles[0] == seen
+    assert E.TRACE_COUNTS["serve"] == traces
 
 
 def test_metrics_summary_fields(trained):
