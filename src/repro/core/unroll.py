@@ -100,15 +100,21 @@ def _perceptron(W, Xb, Yb, M, d, activation, task):
 
 
 def udgd_layer(params_l, S, W, Xb, Yb, cfg: SURFConfig, activation="relu",
-               mix_fn=None, task=None):
+               mix_fn=None, task=None, W_in=None):
     """One unrolled layer. W (n,d); Xb (n,b,F); Yb (n,b). ``mix_fn(W, h)``
     overrides the dense graph filter (e.g. the ring ppermute path); a
     ``takes_S`` mixer is called ``mix_fn(S, W, h)`` instead — the Pallas
-    kernel path (see ``_mix``)."""
+    kernel path (see ``_mix``).
+
+    ``W_in``: the perceptron's input where ``W`` and ``params_l``'s M and
+    d hold only a block of the output columns (a θ split by columns, as
+    the serving solver runs it): all of W's d columns, while the filter,
+    which mixes rows, runs on the block alone."""
     task = resolve_task(cfg, task)
     h, M, d = params_l["h"], params_l["M"], params_l["d"]
     mixed = _mix(mix_fn, S, W, h)
-    return mixed - _perceptron(W, Xb, Yb, M, d, activation, task)
+    return mixed - _perceptron(W if W_in is None else W_in, Xb, Yb, M, d,
+                               activation, task)
 
 
 def udgd_forward(params, S, W0, Xl, Yl, cfg: SURFConfig, activation="relu",

@@ -15,11 +15,23 @@ CI runs the sharded path on simulated host devices:
 ``make test-sharded`` lane) makes ``host_device_count()`` report 8 and
 ``make_surf_mesh(2, 4)`` build a real (seed=2, agent=4) mesh whose
 ``ppermute`` collectives execute with nshards > 1.
+
+``serve_mesh(devices, cfg)`` is the serving layout: a named
+``('agent', 'theta')`` mesh whose 'agent' axis carries REQUEST slots and
+whose 'theta' axis splits θ's perceptron by columns. ``serve_layout``
+picks the smallest θ split whose share fits a device, from the shapes and
+the device's ``bytes_limit`` alone, and gives the other devices to
+requests.
 """
 from __future__ import annotations
 
 import jax
 from jax.sharding import AxisType
+
+# the share of a device's bytes_limit that θ's block may take in serving;
+# the rest holds the working set (request slots, one layer's activations
+# and its bfloat16 copy of M's block, the outputs)
+THETA_HBM_SHARE = 0.8
 
 
 def _make_mesh(shape, axes):
@@ -106,3 +118,45 @@ def make_agent_mesh(n_shards: int | None = None):
             f"{host_device_count()} devices visible (CI: set "
             f"XLA_FLAGS=--xla_force_host_platform_device_count={n})")
     return _make_mesh((n, 1), ("data", "model"))
+
+
+def serve_theta_bytes(cfg, task=None) -> int:
+    """Bytes of θ = {h (L, K+1), M (L, din, d), d (L, d)} in float32,
+    from the configuration's shapes alone."""
+    from repro.core.tasks import resolve_task
+    from repro.core.unroll import perceptron_in_dim
+    task = resolve_task(cfg, task)
+    d, din = task.dim, perceptron_in_dim(cfg, task)
+    return 4 * cfg.n_layers * (din * d + d + cfg.filter_taps + 1)
+
+
+def serve_layout(theta_bytes: int, n_devices: int, bytes_limit=None):
+    """(request_shards, theta_split) for serving a θ of ``theta_bytes``
+    on ``n_devices``: the smallest split, among the divisors of
+    ``n_devices``, whose share of θ takes at most ``THETA_HBM_SHARE`` of
+    a device's ``bytes_limit`` (None: no limit known, θ whole); the other
+    devices serve requests side by side."""
+    for split in range(1, n_devices + 1):
+        if n_devices % split:
+            continue
+        if (bytes_limit is None
+                or theta_bytes / split <= THETA_HBM_SHARE * bytes_limit):
+            return n_devices // split, split
+    raise ValueError(
+        f"serve_layout: θ of {theta_bytes / 1e9:.2f} GB does not fit "
+        f"{n_devices} devices of {bytes_limit / 1e9:.2f} GB each (θ may "
+        f"take {THETA_HBM_SHARE:.0%} of a device)")
+
+
+def serve_mesh(devices, cfg, task=None):
+    """The serving mesh over ``devices``: ``('agent', 'theta')`` shaped by
+    ``serve_layout`` from θ's bytes and the first device's
+    ``memory_stats()['bytes_limit']``. One device gives None: the
+    single-device server, with no mesh at all."""
+    devices = list(devices)
+    if len(devices) == 1:
+        return None
+    limit = (devices[0].memory_stats() or {}).get("bytes_limit")
+    shape = serve_layout(serve_theta_bytes(cfg, task), len(devices), limit)
+    return jax.make_mesh(shape, ("agent", "theta"), devices=devices,
+                         axis_types=(AxisType.Auto,) * 2)

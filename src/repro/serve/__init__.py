@@ -15,7 +15,10 @@ zero at request rate.
     fut.result()["final_acc"]
 
 Layers: ``solver`` (the jitted request-vmapped masked forward;
-``mesh=`` shards the request axis over devices), ``buckets`` (shape
+``mesh=`` shards the request axis over devices, and a mesh with a
+'theta' axis splits θ's perceptron by columns —
+``launch.mesh.serve_mesh(devices, cfg)`` picks the layout from θ's
+bytes and the device's memory), ``buckets`` (shape
 bucketing + provably-inert padding, on the device), ``queue``
 (continuous batching of device-resident slots + futures,
 deadline-aware admission), ``driver`` (``AsyncDriver`` — a
@@ -34,12 +37,15 @@ request's id) with ``serve.submit.featurize`` and ``serve.submit.pad``
 inside it, and each ``tick`` leaves ``serve.tick`` (``reqs``, the ids it
 admitted; ``bucket``) with ``serve.tick.admit``, ``.stack``, ``.call``
 (``bytes_in``, host bytes handed to the solver: the slots' masks and
-``t_real``, since request data is uploaded once, at ``submit``),
+``t_real``, since request data is uploaded once, at ``submit``;
+``devices``, ``theta_bytes`` and ``gather_bytes``, what each device of
+the mesh streams and receives),
 ``.wait`` and ``.unpack`` (one transfer of the outputs, then the
 per-request split) inside it.  Each is a record in ``recs`` (start, end,
 thread CPU seconds, parent) and a ``surf.*`` host event in the profile,
 beside the solver's device operations, which carry the ``surf/mix``,
-``surf/perceptron`` and ``surf/loss`` scopes.  Without a profiler
+``surf/perceptron``, ``surf/loss`` and, with θ split, ``surf/gather``
+scopes.  Without a profiler
 session nothing is recorded (``repro.utils.spans``).
 """
 from repro.serve.buckets import (Bucket, BucketSpec, pad_cohort, pad_probe,

@@ -29,7 +29,12 @@ mesh's agent-role axis (``solver.request_shardings``; ``assemble`` lays
 the batch out that way, so the solver gets its blocks without a
 reshard) — serving is
 embarrassingly parallel, so a batch of B requests splits over devices
-with zero collectives.  ``serve.AsyncDriver`` wraps the server in a
+with zero collectives.  A mesh with a 'theta' axis
+(``launch.mesh.serve_mesh`` makes one when θ does not fit a device)
+also splits θ's perceptron by columns: the server lays θ out once
+(``surf_rules.place_theta``), ``submit`` puts each slot on every device
+with W0's columns split (``solver.slot_shardings``), and the solver
+all-gathers W once a layer.  ``serve.AsyncDriver`` wraps the server in a
 background tick thread (``submit`` returns immediately, ticks fire at a
 cadence); queue mutations are guarded by a server lock so driver ticks
 and caller submits interleave safely.
@@ -64,7 +69,10 @@ from repro.core.tasks import resolve_task
 from repro.serve.buckets import BucketSpec, pad_cohort, pad_probe, slot_mask
 from repro.serve.metrics import ServeMetrics
 from repro.serve.solver import (make_bucket_solver, request_shardings,
-                                resolve_serve_mix)
+                                resolve_serve_mix, slot_shardings,
+                                tick_bytes)
+from repro.sharding.surf_rules import (padded_columns, place_theta,
+                                       theta_split)
 from repro.utils import spans
 from repro.utils.cache import BoundedLRU
 
@@ -139,29 +147,36 @@ class FederationServer:
         if max_wait_ticks < 1:
             raise ValueError(f"max_wait_ticks must be >= 1, got "
                              f"{max_wait_ticks}")
+        self.task = resolve_task(cfg, task)
+        self.cols = self.task.dim
+        slot_out, stack_out = None, None
         if mesh is not None:
             # fail at construction, not at the first tick: the request
             # axis must split evenly over the mesh (ragged TRAFFIC is
             # fine — masked empty slots — but the bucket batch shape
             # is fixed)
-            request_shardings(mesh, int(max_batch), depth)
+            in_sh, _ = request_shardings(mesh, int(max_batch), depth)
+            stack_out = (in_sh[0],) + in_sh[2:-2]
+            slot_out = slot_shardings(mesh, depth)
+            self.cols = padded_columns(self.task.dim, theta_split(mesh))
+            theta = place_theta(theta, mesh, self.task.dim)
         self.depth = depth
         self.max_wait_ticks = int(max_wait_ticks)
         self.cfg = cfg
         self.theta = theta
         self.activation = activation
         self.mix_fn = resolve_serve_mix(mix)
-        self.task = resolve_task(cfg, task)
         self.buckets = buckets if buckets is not None else BucketSpec()
         self.max_batch = int(max_batch)
         self.mesh = mesh
+        self.devices = 1 if mesh is None else int(mesh.size)
         self._cache = BoundedLRU(maxsize=max_buckets, name="serve-buckets")
-        # one program per bucket shape stacks B device slots into the
-        # solver's (B, ...) arguments, laid out as the solver takes them
+        # one program per (true shape, bucket) draws and pads a slot, and
+        # one per bucket shape stacks B device slots into the solver's
+        # (B, ...) arguments, each laid out as the solver takes them
+        self._pad = _pad_program(slot_out)
         self._assemble = jax.jit(_stack_slots, **(
-            {} if mesh is None else
-            {"out_shardings": request_shardings(mesh, self.max_batch,
-                                                depth)[1]}))
+            {} if mesh is None else {"out_shardings": stack_out}))
         self.metrics = ServeMetrics(cache=self._cache)
         self._queue = deque()
         self._ids = itertools.count()
@@ -249,9 +264,8 @@ class FederationServer:
             kw, kb = jax.random.split(key)
             W0 = U.sample_w0(kw, cfg_r, task=self.task)
         with spans.span("serve.submit.pad"):
-            return _sample_and_pad(kb, S, W0, batch, cfg=cfg_r,
-                                   bucket=bucket,
-                                   probe=self.depth == "adaptive")
+            return self._pad(kb, S, W0, batch, cfg=cfg_r, bucket=bucket,
+                             probe=self.depth == "adaptive", cols=self.cols)
 
     def pending(self) -> int:
         """Requests currently queued (admitted-but-unsolved is never
@@ -355,9 +369,13 @@ class FederationServer:
                 for r in admitted:      # free each slot once stacked
                     r.arrays = None
             solve = self._solver(bucket)
+            theta_b, gather_b = tick_bytes(self.cfg, bucket, self.max_batch,
+                                           self.mesh, self.task)
             t0 = time.perf_counter()
             with spans.span("serve.tick.call",
-                            bytes_in=mask.nbytes + t_real.nbytes):
+                            bytes_in=mask.nbytes + t_real.nbytes,
+                            devices=self.devices, theta_bytes=theta_b,
+                            gather_bytes=gather_b):
                 out = solve(stacked[0], self.theta, *stacked[1:], mask,
                             t_real)
             del stacked
@@ -371,7 +389,8 @@ class FederationServer:
                 for i, r in enumerate(admitted):
                     res = {k: np.array(v[i]) for k, v in host.items()
                            if k != "W"}
-                    res["W"] = np.array(host["W"][i, :r.n_real])
+                    res["W"] = np.array(
+                        host["W"][i, :r.n_real, :self.task.dim])
                     lat = now - r.t_submit
                     r.future._set(res, lat)
                     lats.append(lat)
@@ -437,16 +456,26 @@ def _stack_slots(slots):
     return tuple(jnp.stack(a) for a in zip(*slots))
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "bucket", "probe"))
-def _sample_and_pad(kb, S, W0, batch, *, cfg, bucket, probe):
+def _sample_and_pad(kb, S, W0, batch, *, cfg, bucket, probe, cols):
     """One request's padded device slot, one program per (true shape,
     bucket): the layer mini-batches drawn from ``kb`` at the true shape
     (``unroll.sample_layer_batches``: integer draws and a one-hot
     contraction at HIGHEST precision, so bit-identical to the eager
-    draw), then ``pad_cohort`` and, with ``probe``, ``pad_probe`` of the
-    convergence-probe split."""
+    draw), then ``pad_cohort`` (W0's columns zero-padded to ``cols``) and,
+    with ``probe``, ``pad_probe`` of the convergence-probe split."""
     Xl, Yl = U.sample_layer_batches(kb, batch["Xtr"], batch["Ytr"], cfg)
+    W0 = jnp.pad(W0, ((0, 0), (0, cols - W0.shape[1])))
     slot = pad_cohort(S, W0, Xl, Yl, batch["Xte"], batch["Yte"], bucket)
     if probe:
         slot += pad_probe(*U.probe_batch(batch, cfg), bucket)
     return slot
+
+
+@functools.lru_cache(maxsize=None)
+def _pad_program(out_shardings):
+    """``_sample_and_pad`` jitted, its slot laid out as ``out_shardings``
+    (None: on the default device); shared by the servers of a layout."""
+    return jax.jit(_sample_and_pad,
+                   static_argnames=("cfg", "bucket", "probe", "cols"),
+                   **({} if out_shardings is None
+                      else {"out_shardings": out_shardings}))

@@ -21,6 +21,15 @@ fast:
     shape-dependent), so an exact-fit request reproduces
     ``evaluate_surf`` bit-for-bit.
 
+On a mesh with a 'theta' axis (``launch.mesh.serve_mesh``) θ's
+perceptron is split by output columns: each device holds its block of
+M's and d's columns and of W's, with the columns padded with exact zeros
+to a multiple of the split. The graph filter mixes rows, so it runs on
+the block alone; the perceptron needs all of W, which one all-gather a
+layer over the 'theta' axis (named scope ``surf/gather``) brings: W0 is
+gathered before the first layer and each layer's output at its end, where
+the layer's loss and accuracy read it too.
+
 The per-bucket executable cache key extends ``engine._engine_cache_key``
 with the bucket dims; ``engine.TRACE_COUNTS["serve"]`` counts body
 traces (the bench asserts one per warm bucket, zero at request rate).
@@ -28,14 +37,20 @@ traces (the bench asserts one per warm bucket, zero at request rate).
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import engine as TR
 from repro.configs.base import SURFConfig
 from repro.core import unroll as U
 from repro.core.tasks import resolve_task
+from repro.sharding.surf_rules import (_axis_size, axis_for_role,
+                                       check_divides, padded_columns,
+                                       replicated, theta_shardings,
+                                       theta_split)
 
 SERVE_MIXES = (None, "dense", "pallas")
 
@@ -72,30 +87,56 @@ def _masked_scores(task):
     return masked_scores
 
 
-def _serve_core(cfg: SURFConfig, activation, mix_fn=None, task=None):
+def _serve_core(cfg: SURFConfig, activation, mix_fn=None, task=None,
+                theta_axis=None):
     """Single-cohort masked forward ``solve_s(S, theta, W0, Xl, Yl, Xte,
     Yte, mask, t_real)`` at a bucket shape.  ``mask`` (n_pad,) flags real
     agents; ``t_real`` is the request's true test-rows count (its padded
-    rows are row-0 copies — see ``buckets.pad_cohort``)."""
+    rows are row-0 copies — see ``buckets.pad_cohort``).
+
+    ``theta_axis``: the mesh axis θ's columns are split over (inside a
+    ``shard_map``); W0, M, d and the returned W are then this device's
+    column blocks, and each layer all-gathers W once (module doc)."""
     task = resolve_task(cfg, task)
     masked_scores = _masked_scores(task)
+    d = task.dim
+
+    def whole(W):
+        """All of W's real columns, from every device's block."""
+        with jax.named_scope("surf/gather"):
+            return jax.lax.all_gather(W, theta_axis, axis=1,
+                                      tiled=True)[:, :d]
 
     def solve_s(S, theta, W0, Xl, Yl, Xte, Yte, mask, t_real):
         TR.TRACE_COUNTS["serve"] += 1
 
-        def body(W, xs):
-            p_l, Xb, Yb = xs
+        def body(carry, xs):
+            W, W_in = carry     # W_in: all of W's columns (split θ only)
+            p_l, Xb, Yb = xs[:3]
+            if theta_axis is not None:
+                # a loop-variant 1.0 on the layer's block of M: without it
+                # XLA hoists the matmul's bfloat16 cast out of the loop
+                # and casts θ's whole block at once, half again its
+                # bytes, which a device holding a split θ has no room
+                # for (an optimization_barrier on the block does not stop
+                # it: the cast moves through the barrier)
+                p_l = dict(p_l, M=p_l["M"] * (1.0 + 0.0 * xs[3]))
             Wn = U.udgd_layer(p_l, S, W, Xb, Yb, cfg, activation,
-                              mix_fn=mix_fn, task=task)
+                              mix_fn=mix_fn, task=task, W_in=W_in)
             # re-zero padded agents: their perceptron term σ(M[0∥b]+d)
             # is nonzero even on zero inputs (the bias d), and zero S
             # rows only silence them in the NEXT layer's filter
             Wn = jnp.where(mask[:, None], Wn, 0.0)
-            loss, met = masked_scores(Wn, Xte, Yte, mask, t_real)
-            return Wn, (loss, met)
+            Wn_in = None if theta_axis is None else whole(Wn)
+            scored = Wn if Wn_in is None else Wn_in
+            return (Wn, Wn_in), masked_scores(scored, Xte, Yte, mask, t_real)
 
         W0 = jnp.where(mask[:, None], W0, 0.0)
-        W_L, (losses, mets) = jax.lax.scan(body, W0, (theta, Xl, Yl))
+        xs, W0_in = (theta, Xl, Yl), None
+        if theta_axis is not None:
+            xs += (jnp.arange(cfg.n_layers, dtype=W0.dtype),)
+            W0_in = whole(W0)
+        (W_L, _), (losses, mets) = jax.lax.scan(body, (W0, W0_in), xs)
         return {"W": W_L, "loss_per_layer": losses, "acc_per_layer": mets,
                 "final_loss": losses[-1], "final_acc": mets[-1]}
 
@@ -203,27 +244,73 @@ def serve_cache_key(cfg: SURFConfig, bucket, max_batch, activation,
 def request_shardings(mesh, max_batch, depth="fixed"):
     """(in_shardings, out_shardings) for a bucket solver on ``mesh``: the
     REQUEST axis (leading B on every arg and output) shards over the
-    mesh's agent-role axis, theta (arg 1) replicates.  Requests are
-    embarrassingly parallel — the solver runs under a ``shard_map`` with
-    these specs, so each device solves its block of request slots with
-    ZERO collectives (the adaptive path's ``any(active)`` loop predicate
-    is per device).  ``max_batch`` must divide the shard count — ragged
-    tails already ride as masked empty slots, so the constraint is on
-    the BUCKET batch shape, not on traffic."""
-    from repro.sharding.surf_rules import (_axis_size, axis_for_role,
-                                           check_divides, replicated)
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    mesh's agent-role axis; theta (arg 1) replicates, or, on a mesh with
+    a 'theta' axis, splits M's and d's columns over it
+    (``surf_rules.theta_shardings``), with W0's and the returned W's
+    columns split alike.  Requests are embarrassingly parallel — the
+    solver runs under a ``shard_map`` with these specs, so each device
+    solves its block of request slots with no collective of its own (the
+    adaptive path's ``any(active)`` loop predicate is per device); only a
+    θ split adds its all-gathers of W.  ``max_batch`` must divide the
+    shard count — ragged tails already ride as masked empty slots, so
+    the constraint is on the BUCKET batch shape, not on traffic."""
     axis = axis_for_role(mesh, "agent")
     shards = _axis_size(mesh, axis)
     check_divides(max_batch, shards, "the sharded serve batch",
                   "max_batch",
                   "each device solves an equal block of request slots "
                   "(ragged traffic rides as masked empty slots)")
-    rep = replicated(mesh)
-    req = NamedSharding(mesh, P(axis)) if shards > 1 else rep
+    req_axis = axis if shards > 1 else None
+    req = NamedSharding(mesh, P(req_axis))
+    cols = req
+    if theta_split(mesh) > 1:
+        if depth == "adaptive":
+            raise ValueError(
+                "adaptive-depth serving needs θ whole on every device: "
+                "its exit certificate reads all of W's columns each "
+                "layer; serve depth='fixed' on a mesh with a 'theta' axis")
+        cols = NamedSharding(mesh, P(req_axis, None,
+                                     axis_for_role(mesh, "theta")))
     n_args = 11 if depth == "adaptive" else 9
-    in_sh = tuple(rep if i == 1 else req for i in range(n_args))
-    return in_sh, req
+    in_sh = tuple(theta_shardings(mesh) if i == 1 else cols if i == 2
+                  else req for i in range(n_args))
+    out_sh = req if cols is req else {
+        "W": cols, "loss_per_layer": req, "acc_per_layer": req,
+        "final_loss": req, "final_acc": req}
+    return in_sh, out_sh
+
+
+def slot_shardings(mesh, depth="fixed"):
+    """Where one request's padded slot (S, W0, Xl, Yl, Xte, Yte[, Xp,
+    Yp]) lives between ``submit`` and its tick on ``mesh``: on every
+    device, with W0's columns split over the 'theta' axis as the solver
+    takes them, so that stacking a batch moves no slot between
+    devices."""
+    rep = replicated(mesh)
+    w0 = (NamedSharding(mesh, P(None, axis_for_role(mesh, "theta")))
+          if theta_split(mesh) > 1 else rep)
+    n = 8 if depth == "adaptive" else 6
+    return tuple(w0 if i == 1 else rep for i in range(n))
+
+
+@functools.lru_cache(maxsize=64)
+def tick_bytes(cfg: SURFConfig, bucket, max_batch, mesh=None, task=None):
+    """(theta_bytes, gather_bytes) of one call of a bucket executable, per
+    device, from the shapes: the bytes of θ's float32 block the device
+    streams through its layers, and the bytes it receives from the
+    all-gathers of W's columns (L + 1 of them, for its block of request
+    slots; 0 without a θ split). Counted once a (bucket, layout)."""
+    task = resolve_task(cfg, task)
+    split = theta_split(mesh)
+    cols = padded_columns(task.dim, split)
+    din = U.perceptron_in_dim(cfg, task)
+    L_ = cfg.n_layers
+    theta = 4 * L_ * ((din + 1) * cols // split + cfg.filter_taps + 1)
+    slots = max_batch // (1 if mesh is None else
+                          _axis_size(mesh, axis_for_role(mesh, "agent")))
+    gather = (4 * (L_ + 1) * slots * int(bucket.n_agents) * cols
+              * (split - 1) // split)
+    return theta, gather
 
 
 def make_bucket_solver(cfg: SURFConfig, bucket, max_batch, *,
@@ -243,7 +330,9 @@ def make_bucket_solver(cfg: SURFConfig, bucket, max_batch, *,
 
     ``mesh`` shards the request axis over the mesh's agent-role axis
     (``request_shardings``): a bucket's (B, n_pad, ...) stacked cohorts
-    split over devices, zero collectives per request. The split is a
+    split over devices, zero collectives per request; a 'theta' axis
+    splits θ's columns (module doc), W0 and W then having
+    ``surf_rules.padded_columns`` columns. The split is a
     ``shard_map``, not a partitioner decision: the TPU compiler cannot
     partition a Pallas (Mosaic) kernel on its own, so ``mix="pallas"``
     needs each device handed its local block.
@@ -255,18 +344,25 @@ def make_bucket_solver(cfg: SURFConfig, bucket, max_batch, *,
             solve = _serve_core_adaptive(cfg, activation, mix_fn=mix_fn,
                                          task=task)
         else:
+            theta_axis = None
+            if mesh is not None and theta_split(mesh) > 1:
+                theta_axis = axis_for_role(mesh, "theta")
             solve = jax.vmap(
-                _serve_core(cfg, activation, mix_fn=mix_fn, task=task),
+                _serve_core(cfg, activation, mix_fn=mix_fn, task=task,
+                            theta_axis=theta_axis),
                 in_axes=(0, None, 0, 0, 0, 0, 0, 0, 0))
         if mesh is None:
             return jax.jit(solve)
         in_sh, out_sh = request_shardings(mesh, max_batch, depth)
+        spec = functools.partial(jax.tree_util.tree_map, lambda s: s.spec)
         # jax has no varying-axis rule for pallas_call (as in
-        # topology.halo's Pallas resident); the dense path keeps the check
-        solve = jax.shard_map(solve, mesh=mesh,
-                              in_specs=tuple(s.spec for s in in_sh),
-                              out_specs=out_sh.spec,
-                              check_vma=mix_fn is None)
+        # topology.halo's Pallas resident), and types all_gather's result
+        # as varying, though the scores read from the gathered W are the
+        # same on every θ block; the dense unsplit path keeps the check
+        solve = jax.shard_map(solve, mesh=mesh, in_specs=spec(in_sh),
+                              out_specs=spec(out_sh),
+                              check_vma=(mix_fn is None
+                                         and theta_split(mesh) == 1))
         return jax.jit(solve, in_shardings=in_sh, out_shardings=out_sh)
 
     if cache is None:

@@ -1,13 +1,16 @@
 """NamedSharding rules for the SURF meta-training/evaluation engines.
 
-AXIS ROLES, not axis names: every rule shards one of two roles —
+AXIS ROLES, not axis names: every rule shards one of three roles —
 
   * the SEED role (``seed_sharding`` / ``seed_scan_shardings``): the
     leading per-seed axis of the seed-batched engine's stacks;
   * the AGENT role (``agent_sharding`` / ``stacked_*`` / Q rules): the
     agent dimension the halo/ring mixers ``ppermute`` over (the stacked
     eval pool's Q axis is data-parallel over the same devices, so it
-    rides the agent role too).
+    rides the agent role too, and so does the serving REQUEST axis);
+  * the THETA role (``theta_shardings`` / ``place_theta``): the output
+    columns of the serving perceptron M and of its bias d, for a θ too
+    large for one device (``launch.mesh.serve_mesh`` decides the split).
 
 ``axis_for_role`` maps a role to the mesh axis that carries it: the
 named ``'seed'``/``'agent'`` axes of a ``launch.mesh.make_surf_mesh``
@@ -59,7 +62,7 @@ def replicated(mesh: Mesh) -> NamedSharding:
     return NamedSharding(mesh, P())
 
 
-ROLE_AXES = {"seed": "seed", "agent": "agent"}
+ROLE_AXES = {"seed": "seed", "agent": "agent", "theta": "theta"}
 
 
 def check_divides(count, shards, what, noun, fix):
@@ -82,7 +85,8 @@ def axis_for_role(mesh: Mesh, role: str):
     of a ``make_surf_mesh`` 2-D mesh when present, else the legacy 'data'
     axis (1-D shim meshes name their single sharded axis 'data' whatever
     role it plays), else None (nothing to shard over — every rule
-    replicates)."""
+    replicates). The THETA role has no legacy axis: only a mesh that
+    names 'theta' splits θ."""
     try:
         name = ROLE_AXES[role]
     except KeyError:
@@ -90,7 +94,7 @@ def axis_for_role(mesh: Mesh, role: str):
                          f"{sorted(ROLE_AXES)}")
     if name in mesh.axis_names:
         return name
-    if "data" in mesh.axis_names:
+    if role != "theta" and "data" in mesh.axis_names:
         return "data"
     return None
 
@@ -368,3 +372,57 @@ def seed_scan_shardings(mesh: Mesh, n_seeds: int | None = None,
     else:
         ev_sh = rep
     return (seed, stacked_sh, seed, seed, ev_sh, seed), (seed, seed, seed)
+
+
+# ------------------------------------------------------------------ theta
+def theta_split(mesh: Mesh | None) -> int:
+    """How many ways the THETA-role axis splits θ's columns (1: whole θ
+    on every device)."""
+    if mesh is None:
+        return 1
+    return _axis_size(mesh, axis_for_role(mesh, "theta"))
+
+
+def padded_columns(d: int, split: int) -> int:
+    """``d`` rounded up to a multiple of ``split``: the column count of a
+    split θ (and of the served W), so that every device holds an equal
+    block. The extra columns are exact zeros in M, d and W."""
+    return -(-int(d) // int(split)) * int(split)
+
+
+def theta_shardings(mesh: Mesh):
+    """Per-leaf shardings of θ = {h (L, K+1), M (L, din, d), d (L, d)}:
+    M's and d's output columns over the THETA-role axis, the taps h
+    replicated. With no θ split every leaf replicates."""
+    axis = axis_for_role(mesh, "theta")
+    if _axis_size(mesh, axis) <= 1:
+        rep = replicated(mesh)
+        return {"h": rep, "M": rep, "d": rep}
+    return {"h": replicated(mesh),
+            "M": NamedSharding(mesh, P(None, None, axis)),
+            "d": NamedSharding(mesh, P(None, axis))}
+
+
+def _pad_theta_columns(theta, cols, xp=jnp):
+    pad = cols - theta["M"].shape[-1]
+    return {"h": theta["h"],
+            "M": xp.pad(theta["M"], ((0, 0), (0, 0), (0, pad))),
+            "d": xp.pad(theta["d"], ((0, 0), (0, pad)))}
+
+
+def place_theta(theta, mesh: Mesh, d: int):
+    """θ laid out for ``mesh`` (``theta_shardings``), its columns padded
+    with zeros to ``padded_columns(d, theta_split(mesh))``. A θ already
+    laid out passes through untouched; a host θ is padded on the host
+    and each device receives only its block, so a split θ is never whole
+    on one device."""
+    split = theta_split(mesh)
+    cols = padded_columns(d, split)
+    shardings = theta_shardings(mesh)
+    if theta["M"].shape[-1] != cols:
+        if all(isinstance(a, np.ndarray) for a in theta.values()):
+            theta = _pad_theta_columns(theta, cols, np)
+        else:
+            return jax.jit(_pad_theta_columns, static_argnums=1,
+                           out_shardings=shardings)(theta, cols)
+    return jax.device_put(theta, shardings)

@@ -31,13 +31,18 @@ the ``serve.submit`` whose ``req`` is that id.
 Span names (``serve/queue.py``): ``serve.submit`` (``req``) holding
 ``serve.submit.featurize`` and ``serve.submit.pad``; ``serve.tick``
 (``reqs``, ``bucket``) holding ``serve.tick.admit``, ``serve.tick.stack``,
-``serve.tick.call`` (``bytes_in``: host bytes passed, θ excluded),
-``serve.tick.wait`` and ``serve.tick.unpack``.
+``serve.tick.call`` (``bytes_in``: host bytes passed, θ excluded;
+``devices``: the size of the server's mesh; ``theta_bytes``: the bytes of
+θ each device streams in the tick's executable; ``gather_bytes``: the
+bytes each device receives from its all-gathers of W, 0 unless θ is
+split), ``serve.tick.wait`` and ``serve.tick.unpack``.
 
 Device operations carry ``jax.named_scope`` names instead, in their HLO
 metadata (backward operations as ``transpose(jvp(surf/mix))``):
 ``surf/featurize``, ``surf/mix``, ``surf/perceptron``, ``surf/loss``,
-``surf/constraints``, ``surf/clip``, ``surf/adam`` and ``surf/dual``.
+``surf/constraints``, ``surf/clip``, ``surf/adam`` and ``surf/dual``, and,
+where the server splits θ by columns, ``surf/gather`` (the all-gather of
+W that feeds each layer's perceptron).
 """
 from __future__ import annotations
 
