@@ -9,10 +9,13 @@ the padded slot without waiting for the chip; ``tick()`` admits up to
 slots into the bucket's fixed ``(B, n_pad, ...)`` batch with one jitted
 ``assemble`` program (empty slots repeat the first slot and are masked
 out, so the executable never sees a new batch size), solves them in
-one jitted call and fetches the outputs in one transfer, splitting them
-into per-request host results for the futures.  A request's data thus
-crosses the host link once, at ``submit``; only each slot's small
-``mask`` and ``t_real`` are host arrays.
+one jitted call and brings to the host only what the admitted requests'
+futures hold: each admitted slot's W, its real columns, as one fully
+replicated array from a jitted ``take`` program (one transfer from one
+device, whatever the layout; the empty slots' W never leaves the
+device), and the small per-slot leaves (losses, accuracies, depth) in
+one transfer.  A request's data thus crosses the host link once each
+way; only each slot's small ``mask`` and ``t_real`` are host arrays.
 
 The admission rule favors batch fullness without starving rare shapes:
 a tick serves the FULLEST bucket in the queue (ties broken by FIFO head
@@ -47,8 +50,8 @@ padded convergence-probe split, results gain a realized ``depth``, and
 Everything expensive is cached: one executable per (bucket, B, mix,
 task) in a per-server ``BoundedLRU`` (registered as "serve-buckets" for
 ``repro.clear_caches()``), beside one pad program per (true shape,
-bucket) and one ``assemble`` program per bucket shape, all warmed ahead
-of traffic with ``warm()``.
+bucket), one ``assemble`` program per bucket shape and one ``take``
+program per bucket shape, all warmed ahead of traffic with ``warm()``.
 """
 from __future__ import annotations
 
@@ -72,7 +75,7 @@ from repro.serve.solver import (make_bucket_solver, request_shardings,
                                 resolve_serve_mix, slot_shardings,
                                 tick_bytes)
 from repro.sharding.surf_rules import (padded_columns, place_theta,
-                                       theta_split)
+                                       replicated, theta_split)
 from repro.utils import spans
 from repro.utils.cache import BoundedLRU
 
@@ -177,6 +180,12 @@ class FederationServer:
         self._pad = _pad_program(slot_out)
         self._assemble = jax.jit(_stack_slots, **(
             {} if mesh is None else {"out_shardings": stack_out}))
+        # and one per bucket shape takes slot i's W, its real columns, as
+        # one array replicated on every device, so it comes back in one
+        # transfer from one device (``_fetch``)
+        self._take = jax.jit(
+            functools.partial(_take_slot, dim=self.task.dim),
+            **({} if mesh is None else {"out_shardings": replicated(mesh)}))
         self.metrics = ServeMetrics(cache=self._cache)
         self._queue = deque()
         self._ids = itertools.count()
@@ -337,6 +346,22 @@ class FederationServer:
         return max(counts, key=lambda b: (
             min(counts[b], self.max_batch), -first_pos[b]))
 
+    def _fetch(self, out, k):
+        """The results of the solver's first ``k`` slots on the host, and
+        the bytes brought: each slot's W, its real columns, taken on the
+        device as one replicated array (``_take_slot``; its transfer
+        started at once, so the ``k`` overlap), then every slot's small
+        leaves in one ``device_get``.  The empty slots' W stays on the
+        device."""
+        Ws = [self._take(out["W"], np.int32(i)) for i in range(k)]
+        for W in Ws:
+            W.copy_to_host_async()
+        small = jax.device_get({key: v for key, v in out.items()
+                                if key != "W"})
+        Ws = [np.asarray(W) for W in Ws]
+        nbytes = sum(a.nbytes for a in Ws + list(small.values()))
+        return Ws, small, nbytes
+
     def tick(self) -> int:
         """One continuous-batching step: pick a bucket
         (``_select_bucket``), admit up to ``max_batch`` of its requests
@@ -344,7 +369,12 @@ class FederationServer:
         requests age by one tick.  Returns the number of requests
         completed (0 on an empty queue).  Bucket selection and admission
         run under the server lock (an async driver may tick while
-        submits keep landing); the solve itself does not."""
+        submits keep landing); the solve itself does not.  Admitted
+        requests hold the batch's first slots, so the tick fetches only
+        those slots' W (``_fetch``: one replicated array a request, its
+        real columns) and the small leaves, never the empty slots' W;
+        the ``serve.tick.unpack`` span counts the bytes as
+        ``bytes_out``."""
         with spans.span("serve.tick") as tick_span:
             with spans.span("serve.tick.admit"), self._lock:
                 if not self._queue:
@@ -384,16 +414,15 @@ class FederationServer:
             now = time.perf_counter()
             wall = now - t0
             lats = []
-            with spans.span("serve.tick.unpack"):
-                host = jax.device_get(out)
-                for i, r in enumerate(admitted):
-                    res = {k: np.array(v[i]) for k, v in host.items()
-                           if k != "W"}
-                    res["W"] = np.array(
-                        host["W"][i, :r.n_real, :self.task.dim])
+            with spans.span("serve.tick.unpack") as unpack_span:
+                Ws, host, nbytes = self._fetch(out, len(admitted))
+                for i, (r, W) in enumerate(zip(admitted, Ws)):
+                    res = {k: np.array(v[i]) for k, v in host.items()}
+                    res["W"] = np.array(W[:r.n_real])
                     lat = now - r.t_submit
                     r.future._set(res, lat)
                     lats.append(lat)
+                unpack_span.set(bytes_out=nbytes)
             useful = sum(r.n_real * r.rows_real for r in admitted)
             padded = self.max_batch * int(bucket.n_agents) * int(bucket.rows)
             kw = {}
@@ -420,11 +449,12 @@ class FederationServer:
         (n_agents, test_rows) pairs.  Each pair gets its featurization
         and pad program built by padding an all-zero request of that
         true shape (``cfg.train_per_agent`` training rows); each distinct
-        bucket they map to gets its ``assemble`` program and executable,
-        run once on a batch of such a slot under all-false masks, through
-        the same path as a tick (identical jit signatures to real traffic
-        — exactly ONE solver body trace per bucket, which
-        ``launch.surf_serve`` asserts).  Returns the warmed buckets."""
+        bucket they map to gets its ``assemble`` program, executable and
+        ``take`` program, run once on a batch of such a slot under
+        all-false masks, through the same path as a tick (identical jit
+        signatures to real traffic — exactly ONE solver body trace per
+        bucket, which ``launch.surf_serve`` asserts).  Returns the warmed
+        buckets."""
         ydt = self.task.label_dtype
         F, m = self.task.feat_dim, int(self.cfg.train_per_agent)
         slots = {}
@@ -441,7 +471,7 @@ class FederationServer:
             stacked, mask, t_real = self._batch(bucket, [slot])
             out = self._solver(bucket)(stacked[0], self.theta, *stacked[1:],
                                        mask, t_real)
-            jax.device_get(out)
+            self._fetch(out, 1)
         return list(slots)
 
     def cache_stats(self) -> dict:
@@ -454,6 +484,13 @@ def _stack_slots(slots):
     tuple of (B, ...) stacked solver arguments; jitted per server as its
     ``assemble`` program."""
     return tuple(jnp.stack(a) for a in zip(*slots))
+
+
+def _take_slot(W, i, *, dim):
+    """Slot ``i`` (a traced int32, so one program a bucket shape) of the
+    solver's stacked W, its first ``dim`` columns: jitted per server as
+    its ``take`` program."""
+    return jax.lax.dynamic_index_in_dim(W, i, 0, keepdims=False)[:, :dim]
 
 
 def _sample_and_pad(kb, S, W0, batch, *, cfg, bucket, probe, cols):

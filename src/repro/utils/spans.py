@@ -35,7 +35,8 @@ Span names (``serve/queue.py``): ``serve.submit`` (``req``) holding
 ``devices``: the size of the server's mesh; ``theta_bytes``: the bytes of
 θ each device streams in the tick's executable; ``gather_bytes``: the
 bytes each device receives from its all-gathers of W, 0 unless θ is
-split), ``serve.tick.wait`` and ``serve.tick.unpack``.
+split), ``serve.tick.wait`` and ``serve.tick.unpack`` (``bytes_out``: the
+bytes brought to the host, the admitted slots' W and the small leaves).
 
 Device operations carry ``jax.named_scope`` names instead, in their HLO
 metadata (backward operations as ``transpose(jvp(surf/mix))``):

@@ -9,6 +9,7 @@ every test then serves NEW federations through it — the amortization
 claim under test.
 """
 import dataclasses
+import time
 
 import jax
 import numpy as np
@@ -24,6 +25,7 @@ from repro.data import synthetic
 from repro.serve import (AsyncDriver, Bucket, BucketSpec,
                          FederationServer, ServeMetrics, pad_cohort,
                          pad_probe, serve_cache_key, slot_mask)
+from repro.utils import spans
 from repro.utils.cache import BoundedLRU
 
 CFG = SMOKE
@@ -280,33 +282,68 @@ def test_queued_request_holds_device_slot_of_bucket_shape(trained, depth):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-@pytest.mark.parametrize("k", [3, 8])
-def test_tick_matches_host_stacked_batch_bitwise(trained, k):
+KS = (1, 3, 8)
+
+
+@pytest.fixture(scope="module")
+def ticked(trained, tmp_path_factory):
+    """One server (``max_batch=8``) answering one tick of each ``k`` in
+    ``KS`` under a profiler session: {k: (requests, futures, the same
+    executable's output on the host-stacked numpy batch of the same
+    slots, zero-filled past the ``k`` admitted, the tick's
+    ``serve.tick.unpack`` record)}."""
+    state, _ = trained
+    srv = _server(state.theta, max_batch=8)
+    solve = srv._solver(Bucket(8, 4))
+    ticks = {}
+    with jax.profiler.trace(str(tmp_path_factory.mktemp("ticked"))):
+        for k in KS:
+            futs = [srv.submit(*_cohort([6, 8][i % 2], 4, seed=100 + i)[1:],
+                               seed=i) for i in range(k)]
+            reqs = list(srv._queue)
+            slots = [[np.asarray(a) for a in r.arrays] for r in reqs]
+            slots += [[np.zeros_like(a) for a in slots[0]]] * (8 - k)
+            args = [np.stack(a) for a in zip(*slots)]
+            mask = np.stack([r.mask for r in reqs]
+                            + [np.zeros(8, bool)] * (8 - k))
+            t_real = np.array([r.t_real for r in reqs] + [4.0] * (8 - k),
+                              np.float32)
+            ref = jax.device_get(solve(args[0], state.theta, *args[1:],
+                                       mask, t_real))
+            t0 = time.perf_counter()
+            assert srv.tick() == k
+            (unpack,) = [r for r in spans.records()
+                         if r.t0 >= t0 and r.name == "serve.tick.unpack"]
+            ticks[k] = reqs, futs, ref, unpack
+    return ticks
+
+
+@pytest.mark.parametrize("k", KS)
+def test_tick_matches_host_stacked_batch_bitwise(ticked, k):
     """A tick's device-assembled batch solves to exactly what the same
     executable returns on the host-stacked numpy batch of the same
     slots, zero-filled past the ``k`` admitted requests: the empty slots'
-    repeats of the first slot reach no result."""
-    state, _ = trained
-    srv = _server(state.theta, max_batch=8)
-    futs = [srv.submit(*_cohort([6, 8][i % 2], 4, seed=100 + i)[1:],
-                       seed=i) for i in range(k)]
-    reqs = list(srv._queue)
-    slots = [[np.asarray(a) for a in r.arrays] for r in reqs]
-    slots += [[np.zeros_like(a) for a in slots[0]]] * (8 - k)
-    args = [np.stack(a) for a in zip(*slots)]
-    mask = np.stack([r.mask for r in reqs]
-                    + [np.zeros(8, bool)] * (8 - k))
-    t_real = np.array([r.t_real for r in reqs] + [4.0] * (8 - k),
-                      np.float32)
-    ref = jax.device_get(srv._solver(Bucket(8, 4))(
-        args[0], state.theta, *args[1:], mask, t_real))
-    assert srv.tick() == k
+    repeats of the first slot reach no result, and the per-slot fetch
+    brings each admitted slot's W unchanged."""
+    reqs, futs, ref, _ = ticked[k]
+    d = resolve_task(CFG).dim
     for i, (r, f) in enumerate(zip(reqs, futs)):
         res = f.result()
-        np.testing.assert_array_equal(res["W"], ref["W"][i, :r.n_real])
+        np.testing.assert_array_equal(res["W"], ref["W"][i, :r.n_real, :d])
         for key in ("final_loss", "final_acc", "loss_per_layer",
                     "acc_per_layer"):
             np.testing.assert_array_equal(res[key], ref[key][i])
+
+
+@pytest.mark.parametrize("k", KS)
+def test_unpack_counts_the_bytes_it_brings(ticked, k):
+    """``bytes_out`` of ``serve.tick.unpack``: the ``k`` admitted slots'
+    W (n_pad × dim float32 each; the empty slots' W is not fetched) and
+    the small leaves of all 8 slots."""
+    _, _, ref, unpack = ticked[k]
+    small = sum(v.nbytes for key, v in ref.items() if key != "W")
+    assert unpack.attrs["bytes_out"] == (
+        k * 8 * resolve_task(CFG).dim * 4 + small)
 
 
 def test_results_do_not_share_memory(trained):
@@ -335,6 +372,30 @@ def test_warm_then_one_request_leaves_ticks_compile_free(trained,
     for i, (S, ds) in enumerate(cohorts[1:]):
         srv.submit(S, ds, seed=1 + i)
     assert srv.tick() == 3
+    assert compiles[0] == seen
+    assert E.TRACE_COUNTS["serve"] == traces
+
+
+@pytest.fixture(scope="module")
+def warmed(trained):
+    """A server (``max_batch=4``) after ``warm()`` alone."""
+    state, _ = trained
+    srv = _server(state.theta)
+    srv.warm([(6, 4)])
+    return srv
+
+
+@pytest.mark.parametrize("k", [1, 2, 3, 4])
+def test_warm_leaves_ticks_at_every_admitted_count_compile_free(
+        warmed, compiles, k):
+    """After ``warm()``, a tick of any admitted count up to ``max_batch``
+    traces and compiles nothing: the per-slot fetch takes its slot as a
+    traced index, one program a bucket shape."""
+    cohorts = [_cohort(6, 4, seed=130 + 10 * k + i)[1:] for i in range(k)]
+    seen, traces = compiles[0], E.TRACE_COUNTS["serve"]
+    futs = [warmed.submit(S, ds, seed=i) for i, (S, ds) in enumerate(cohorts)]
+    assert warmed.tick() == k
+    assert all(f.done() for f in futs)
     assert compiles[0] == seen
     assert E.TRACE_COUNTS["serve"] == traces
 

@@ -5,8 +5,9 @@ reference, which need no mesh.
 
 The subprocess (``_probe``) serves one tiny 62-class federation set
 (F = 8, n = 12, L = 2: d = 558, which 4 does not divide) through
-``FederationServer`` on meshes that split θ 2 and 4 ways, and reports
-what the tests below compare."""
+``FederationServer`` on one device, on meshes that split θ 2 and 4 ways
+and on one that splits request slots 4 ways, in ticks of 1, 3 and 8
+admitted requests, and reports what the tests below compare."""
 import dataclasses
 import json
 import os
@@ -27,6 +28,11 @@ CFG = SURFConfig(n_agents=12, n_layers=2, filter_taps=2, feature_dim=8,
                  n_classes=62, batch_per_agent=4, train_per_agent=8,
                  test_per_agent=4, eps=0.05, topology="regular", degree=3)
 SPLITS = (2, 4)
+# the server's mesh ('agent', 'theta') by layout; None: one device
+LAYOUTS = {"one": None, "split_2": (2, 2), "split_4": (1, 4),
+           "requests_4": (4, 1)}
+KS = (1, 3, 8)
+WARM_KS = (1, 3, 4)
 V5E_LIMIT = 15.75e9
 SEED = 2 ** 40 + 12345
 
@@ -45,7 +51,7 @@ def _cohorts(cfg):
     from repro.core import surf
     from repro.data import synthetic
     out = []
-    for i, n in enumerate([12, 10, 12]):
+    for i, n in enumerate([12, 10] * 4):
         cfg_r = dataclasses.replace(cfg, n_agents=n)
         _, S = surf.make_problem(cfg_r, seed=50 + i)
         out.append((cfg_r, np.asarray(S),
@@ -78,26 +84,40 @@ def _probe():
     out = {"d": d}
     with jax.default_matmul_precision("highest"):
         refs = [surf.solve_federation(c, state, S, ds, seed=i)
-                for i, (c, S, ds) in enumerate(reqs)]
+                for i, (c, S, ds) in enumerate(reqs[:3])]
         served = {}
-        for split in (1,) + SPLITS:
+        for layout, shape in LAYOUTS.items():
             srv = FederationServer(
-                CFG, theta, max_batch=4, buckets=buckets,
-                mesh=None if split == 1 else mesh((4 // split, split)))
-            futs = [srv.submit(S, ds, seed=i)
-                    for i, (_, S, ds) in enumerate(reqs)]
-            srv.drain()
-            served[split] = [f.result() for f in futs]
-            if split == 1:
+                CFG, theta, max_batch=8, buckets=buckets,
+                mesh=None if shape is None else mesh(shape))
+            served[layout] = {}
+            for k in KS:
+                futs = [srv.submit(S, ds, seed=i)
+                        for i, (_, S, ds) in enumerate(reqs[:k])]
+                whole = _whole_fetch(srv, d)
+                assert srv.tick() == k
+                served[layout][k] = [f.result() for f in futs]
+                out[f"exact_{layout}_{k}"] = all(
+                    np.array_equal(a[key], b[key])
+                    for a, b in zip(served[layout][k], whole) for key in b)
+            out[f"w_shapes_{layout}"] = [
+                list(r["W"].shape) for k in KS for r in served[layout][k]]
+            if shape is None:
                 continue
+            out[f"one_gap_{layout}"] = max(
+                float(np.max(np.abs(a[key] - b[key])))
+                for k in KS
+                for a, b in zip(served[layout][k], served["one"][k])
+                for key in ("W", "final_loss", "loss_per_layer"))
+            if shape[1] == 1:
+                continue
+            split = shape[1]
             out[f"ref_gap_{split}"] = max(
                 max(abs(float(r[k]) - float(ref[k]))
                     for k in ("final_loss", "final_acc"))
-                for r, ref in zip(served[split], refs))
-            out[f"one_gap_{split}"] = max(
-                float(np.max(np.abs(a["W"] - b["W"])))
-                for a, b in zip(served[split], served[1]))
-            out[f"w_shape_{split}"] = list(served[split][0]["W"].shape)
+                for r, ref in zip(served[layout][3], refs))
+            out[f"one_gap_{split}"] = out[f"one_gap_{layout}"]
+            out[f"w_shape_{split}"] = list(served[layout][3][0]["W"].shape)
             # the solver's own output, padded columns included
             bucket = buckets.bucket_for(12, 4)
             slot = srv._slot(reqs[0][1], reqs[0][2], bucket, 0, 0)
@@ -123,11 +143,15 @@ def _probe():
                            mesh=mesh((2, 2)))
     srv.warm([(12, 4), (10, 4)])
     traces, n_compiles = TRACE_COUNTS["serve"], len(compiles)
-    futs = [srv.submit(S, ds, seed=i) for i, (_, S, ds) in enumerate(reqs)]
-    srv.tick()
+    done = 0
+    for k in WARM_KS:
+        futs = [srv.submit(S, ds, seed=i)
+                for i, (_, S, ds) in enumerate(reqs[:k])]
+        srv.tick()
+        done += sum(f.done() for f in futs)
     out["after_warm_traces"] = TRACE_COUNTS["serve"] - traces
     out["after_warm_compiles"] = len(compiles) - n_compiles
-    out["after_warm_done"] = sum(f.done() for f in futs)
+    out["after_warm_done"] = done
 
     # a host θ lands padded, each device holding only its block
     host = {k: np.asarray(v) for k, v in theta.items()}
@@ -160,6 +184,25 @@ def _probe():
         out[f"job_{fault}"] = {"checks": r["checks"],
                                "layout": r["info"]["layout"]}
     print(json.dumps(out))
+
+
+def _whole_fetch(srv, d):
+    """The queued requests' results as the solver's whole output, fetched
+    at once and then sliced: the batch ``tick`` would make of the queue,
+    solved by the same executable."""
+    import jax
+    reqs = list(srv._queue)
+    bucket = reqs[0].bucket
+    stacked, m, t = srv._batch(bucket, [r.arrays for r in reqs],
+                               [r.mask for r in reqs],
+                               [r.t_real for r in reqs])
+    host = jax.device_get(srv._solver(bucket)(stacked[0], srv.theta,
+                                              *stacked[1:], m, t))
+    res = [{key: v[i] for key, v in host.items() if key != "W"}
+           for i in range(len(reqs))]
+    for i, r in enumerate(reqs):
+        res[i]["W"] = host["W"][i, :r.n_real, :d]
+    return res
 
 
 def _bench_cell(harness):
@@ -209,8 +252,25 @@ def test_d_is_not_divisible_by_the_widest_split(probe):
     assert probe["d"] % 4 != 0
 
 
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+@pytest.mark.parametrize("k", KS)
+def test_served_results_are_the_solvers_output_bitwise(probe, layout, k):
+    """With ``k`` admitted, every served result equals, bit for bit, the
+    same executable's whole output fetched at once and sliced: the
+    per-slot fetch changes no number, on one device, θ split 2 or 4 ways,
+    or request slots over 4 devices."""
+    assert probe[f"exact_{layout}_{k}"]
+
+
+def test_request_split_server_matches_single_device_server(probe):
+    """Request slots over a 4 × 1 mesh, θ whole on each device: the
+    results of ticks of 1, 3 and 8 admitted against one device."""
+    assert probe["one_gap_requests_4"] < 1e-5
+    assert probe["w_shapes_requests_4"] == probe["w_shapes_one"]
+
+
 def test_tick_after_warm_traces_and_compiles_nothing(probe):
-    assert probe["after_warm_done"] == 3
+    assert probe["after_warm_done"] == sum(WARM_KS)
     assert probe["after_warm_traces"] == 0
     assert probe["after_warm_compiles"] == 0
 
