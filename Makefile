@@ -6,67 +6,11 @@
 #                       nshards > 1 (they skip on a 1-device run), including
 #                       the 2-D (seed=2, agent=4) and (seed=4, agent=2)
 #                       make_surf_mesh shapes of tests/test_mesh2d.py
-#   make bench        — SURF paper-figure benchmark battery (slow)
-#   make bench-scan   — scan-engine perf tracking: BENCH_scan_engine.json
-#   make bench-topology — dense/ring/halo mixing across graph families:
-#                       BENCH_topology.json
-#   make bench-engine — unified-engine smoke: ASSERTS a seed-batched
-#                       scheduled run traces meta_step exactly once and
-#                       the scheduled-halo path moves fewer collective
-#                       bytes than dense S_t @ W: BENCH_engine.json
-#   make bench-mesh2d — 2-D mesh smoke: ASSERTS a seed-batched scheduled-
-#                       HALO run on a (seed=2, agent=4) mesh traces
-#                       meta_step exactly once and the halo exchange under
-#                       the seed vmap moves fewer collective bytes than
-#                       the dense per-lane S_i @ W: BENCH_mesh2d.json
-#   make bench-tasks  — task-layer smoke: ASSERTS classification AND
-#                       sparse recovery each trace meta_step exactly once
-#                       through the one engine, and sparse-recovery eval
-#                       NMSE decreases monotonically with unrolled depth
-#                       L in {3, 6, 10} (best of 3 training restarts per
-#                       depth): BENCH_tasks.json
-#   make bench-kernels — graph-filter Pallas kernel vs jnp Horner, forward
-#                       + grad over an (n, d) grid incl. the paper scale
-#                       (n=100, d=650, K=2): ASSERTS forward/(dS, dW, dh)
-#                       parity and trace-count==1 for a mix="pallas"
-#                       engine run; stamps backend + interpret mode (CPU
-#                       numbers are interpret-mode correctness timings):
-#                       BENCH_kernels.json
-#   make bench-serve  — amortized-solver serving: replays a >=200-request
-#                       synthetic trace (>=2 shape buckets) through the
-#                       continuous-batching server; ASSERTS one serve
-#                       trace per warm bucket, zero replay traces, and
-#                       per-request parity vs the single-cohort reference
-#                       solve; stamps federations/s, p50/p99 latency,
-#                       pad-waste, backend + interpret mode; plus
-#                       sharded+async rows (mesh-sharded request axis +
-#                       AsyncDriver per shard count in {1,2,4,8}, parity
-#                       spot-checked, device_count + mesh fingerprints +
-#                       simulated-device caveat stamped):
-#                       BENCH_serve.json
-#   make bench-qsharded — Q-sharded train engine on 8 simulated devices:
-#                       ASSERTS trace-count==1 with in-scan Q-sharded
-#                       snapshot evals, allclose parity vs the replicated
-#                       run, and per-meta-step collective bytes FLAT over
-#                       Q -> 2Q -> 4Q (masked-psum select) while the
-#                       naive dynamic-index counterfactual grows ∝ Q:
-#                       BENCH_qsharded.json
-#   make bench-earlyexit — convergence-adaptive depth: sweeps
-#                       exit_threshold through the early-exit while-loop
-#                       solver; ASSERTS thr=0 parity with the fixed-L
-#                       forward (depth==L, W_L allclose, bit-identical
-#                       RNG stream), one adaptive trace per threshold +
-#                       zero on re-eval, mean realized depth < L at
-#                       matched accuracy (|Δacc| <= eps), and a
-#                       populated serve-path depth histogram; emits the
-#                       fig5 depth-vs-accuracy frontier rows; stamps
-#                       backend + interpret mode: BENCH_earlyexit.json
+# The benchmark is bench/ (see BENCHMARK.json): python3 bench/run.py.
 PY ?= python
 export PYTHONPATH := src$(if $(PYTHONPATH),:$(PYTHONPATH),)
 
-.PHONY: test test-fast test-sharded bench bench-scan bench-topology \
-	bench-engine bench-mesh2d bench-tasks bench-kernels bench-serve \
-	bench-qsharded bench-earlyexit
+.PHONY: test test-fast test-sharded
 
 test:
 	$(PY) -m pytest -x -q
@@ -77,33 +21,3 @@ test-fast:
 test-sharded:
 	XLA_FLAGS="--xla_force_host_platform_device_count=8" \
 	REPRO_SHARDED_LANE=1 $(PY) -m pytest -x -q -m "not slow"
-
-bench:
-	$(PY) -m benchmarks.run
-
-bench-scan:
-	sh scripts/bench.sh scan
-
-bench-topology:
-	sh scripts/bench.sh topology
-
-bench-engine:
-	sh scripts/bench.sh engine
-
-bench-mesh2d:
-	sh scripts/bench.sh mesh2d
-
-bench-tasks:
-	sh scripts/bench.sh tasks
-
-bench-kernels:
-	sh scripts/bench.sh kernels
-
-bench-serve:
-	sh scripts/bench.sh serve
-
-bench-qsharded:
-	sh scripts/bench.sh qsharded
-
-bench-earlyexit:
-	sh scripts/bench.sh earlyexit

@@ -1,6 +1,5 @@
 """Serve an assigned architecture with batched requests: prefill + greedy
-decode through the KV/state-cache path (reduced config on CPU; the full
-configs lower on the production mesh via launch/dryrun.py).
+decode through the KV/state-cache path (reduced config on CPU).
 
   PYTHONPATH=src python examples/serve_arch.py --arch jamba-1.5-large-398b
 """

@@ -5,6 +5,7 @@ reduced Qwen3 variant for a few hundred steps on the synthetic pipeline.
 """
 import os
 import sys
+import tempfile
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
@@ -15,5 +16,5 @@ if __name__ == "__main__":
     use_compilation_cache()
     args = sys.argv[1:] or ["--arch", "qwen3-4b", "--steps", "200",
                             "--batch", "8", "--seq", "128", "--lr", "3e-3",
-                            "--ckpt", "bench_out/train_lm_ckpt"]
+                            "--ckpt", tempfile.mkdtemp(prefix="train_lm_")]
     train_main(args)

@@ -2,20 +2,18 @@
 # learning. graph topologies / U-DGD unrolled layers / descending
 # constraints / primal-dual meta-training / FL baselines.
 #
-# ``trainer`` (the compat shim over ``repro.engine``) and ``surf`` are
-# NOT imported eagerly: both depend on the engine package, which itself
-# imports ``repro.core.constraints``/``task``/``unroll`` — eager imports
-# here would close that cycle when ``repro.engine`` is imported first.
-# ``from repro.core import trainer`` / ``import repro.core.surf`` work
-# via Python's on-demand submodule resolution, and attribute access
+# ``surf`` is NOT imported eagerly: it depends on the engine package,
+# which itself imports ``repro.core.constraints``/``tasks``/``unroll`` —
+# an eager import here would close that cycle when ``repro.engine`` is
+# imported first. ``import repro.core.surf`` works via Python's
+# on-demand submodule resolution, and attribute access
 # (``repro.core.surf`` after ``import repro.core``) via the PEP 562
 # module __getattr__ below.
-from repro.core import baselines, constraints, graph, task, unroll
+from repro.core import baselines, constraints, unroll
 
-__all__ = ["graph", "task", "unroll", "constraints", "trainer", "baselines",
-           "surf"]
+__all__ = ["unroll", "constraints", "baselines", "surf"]
 
-_LAZY = ("trainer", "surf")
+_LAZY = ("surf",)
 
 
 def __getattr__(name):

@@ -22,11 +22,11 @@ import numpy as np
 
 from repro import engine as TR
 from repro.configs.base import SURFConfig
-from repro.core import graph as G
 from repro.core import unroll as U
 from repro.core.tasks import (classification_task, resolve_task,  # noqa: F401
                               sparse_recovery_task)
 from repro.data.pipeline import stack_meta_datasets
+from repro.topology import families as G
 from repro.utils.cache import BoundedLRU
 
 

@@ -2,10 +2,9 @@
 
 ``Task`` is the interface (``base.py``); ``resolve_task(cfg, task)`` is
 the single resolution point every consumer funnels through. Shipped
-implementations: ``ClassificationTask`` (the paper's softmax head,
-bit-exact port of the legacy ``core/task.py``) and ``SparseRecoveryTask``
-(federated LASSO). See ``engine/README.md`` §Tasks for the contract and
-how to add one.
+implementations: ``ClassificationTask`` (the paper's softmax head) and
+``SparseRecoveryTask`` (federated LASSO). See ``engine/README.md``
+§Tasks for the contract and how to add one.
 """
 from repro.core.tasks.base import Task, resolve_task
 from repro.core.tasks.classification import (ClassificationTask,

@@ -8,7 +8,8 @@ agent's weight row, how a mini-batch flattens into the perceptron input
 (``batch_vector``), the dataset synthesis hook, and a stable
 ``cache_tag``; the federated lifts (``fl_loss`` / ``fl_metric`` /
 ``fl_grad`` / ``grad_norm``) and the W0 sampler (``init_state``) are
-shared here and reproduce the legacy ``core/task.py`` math bit-exactly.
+shared here and reproduce ``core.tasks.classification``'s module-level
+functions bit-exactly.
 
 The engine never branches on the task kind — it only calls this
 interface — which is what makes classification and sparse recovery run

@@ -6,10 +6,9 @@ d = F·C + C. The paper freezes a ResNet18; here features come from
 ``data/synthetic.py`` (offline container) or from any assigned
 architecture's final hidden state via ``features_from_backbone``.
 
-The module-level functions are the legacy ``core/task.py`` API (moved
-here verbatim — ``core/task.py`` re-exports them as a compat shim);
-``ClassificationTask`` wraps them behind the generic ``Task`` interface
-so the engine traces the identical graph either way.
+The module-level functions take the feature and class counts as
+arguments; ``ClassificationTask`` wraps them behind the generic ``Task``
+interface so the engine traces the identical graph either way.
 """
 from __future__ import annotations
 

@@ -1,4 +1,4 @@
-"""The streaming SURF engine (extracted from ``core.trainer``):
+"""The streaming SURF engine:
 
   * ``engine.core``      — S-as-argument meta-step / eval bodies,
                            ``TrainState``, compiled-engine cache keys;
@@ -10,8 +10,7 @@
                            cadence;
   * ``engine.resume``    — donate-through-checkpoint restore.
 
-``core.trainer`` re-exports this module's names as a compat shim; new
-code should import from here. Cache-key anatomy: ``engine/README.md``.
+Cache-key anatomy: ``engine/README.md``.
 """
 from repro.engine import resume, seeds, snapshots  # noqa: F401
 from repro.engine.core import (  # noqa: F401

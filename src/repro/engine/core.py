@@ -13,8 +13,7 @@ Keeping S OUT of the closures (``meta_step_s(S, state, batch, key)``,
 topology/seed of the same config — S rides through jit as a device
 argument. The drivers live in ``engine.scan`` (single-seed streaming
 scan), ``engine.seeds`` (seed-batched outer vmap), ``engine.snapshots``
-(in-scan evaluation) and ``engine.resume`` (donate-through-checkpoint);
-``core.trainer`` re-exports everything as a compat shim.
+(in-scan evaluation) and ``engine.resume`` (donate-through-checkpoint).
 
 ``mix_fn`` replaces the dense graph filter with a collective-efficient
 exchange (``core.ring.make_ring_mix`` / ``topology.halo.make_halo_mix``).

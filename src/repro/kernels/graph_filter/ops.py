@@ -14,8 +14,7 @@ meta-training hot path.
 Dispatch rules (``graph_filter``, the single public entry point):
 
   * argument order is ``(S, W, h)``, matching ``core.unroll.graph_filter``
-    and the engine's mixer protocol. The pre-unification ``(h, S, W)``
-    order survives only as the deprecated ``graph_filter_hsw`` alias.
+    and the engine's mixer protocol.
   * ``interpret=None`` auto-selects by backend: COMPILED Pallas on
     TPU/GPU, the Pallas interpreter everywhere else (CPU has no Mosaic
     target — interpreter mode is a correctness path, not a perf path).
@@ -146,14 +145,6 @@ def graph_filter(S, W, h, block_d=None, interpret=None, impl="pallas"):
         return graph_filter_ref(S, W, h)
     bd = pick_block_d(n, d) if block_d is None else int(block_d)
     return _graph_filter(h, S, W, bd, resolve_interpret(interpret))
-
-
-def graph_filter_hsw(h, S, W, block_d=None, interpret=None, impl="pallas"):
-    """DEPRECATED pre-unification argument order — use
-    ``graph_filter(S, W, h)``. Kept so external callers of the original
-    kernel API keep working; see the package docstring."""
-    return graph_filter(S, W, h, block_d=block_d, interpret=interpret,
-                        impl=impl)
 
 
 def make_pallas_mix(*, block_d=None, interpret=None, tag=None):

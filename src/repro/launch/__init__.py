@@ -1,6 +1,4 @@
-# launch: meshes, input specs, sharded steps, dry-run, roofline, drivers.
-# NOTE: repro.launch.dryrun sets XLA_FLAGS at import — never import it from
-# library code; it is an entry point only.
-from repro.launch import mesh, roofline, hlo_cost  # light, device-free
+# launch: meshes, input specs, sharded steps, HLO cost counts, drivers.
+from repro.launch import mesh, hlo_cost  # light, device-free
 
-__all__ = ["mesh", "roofline", "hlo_cost"]
+__all__ = ["mesh", "hlo_cost"]

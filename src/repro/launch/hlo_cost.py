@@ -1,6 +1,6 @@
 """HLO-text cost model with WHILE-LOOP TRIP-COUNT accounting.
 
-Motivation (measured, see EXPERIMENTS.md §Dry-run): XLA's
+Motivation: XLA's
 ``compiled.cost_analysis()`` reports a while body ONCE — a scan-over-layers
 model is undercounted by ~n_layers×. This module re-derives
 (flops, bytes accessed, per-kind collective bytes) by parsing the
@@ -17,7 +17,7 @@ post-SPMD HLO of ``compiled.as_text()``:
     collective-permute = result; n parsed from replica_groups.
 
 All shapes in the post-SPMD module are per-device, so every number here is
-a PER-CHIP quantity — exactly what the roofline terms need.
+a PER-CHIP quantity.
 """
 from __future__ import annotations
 

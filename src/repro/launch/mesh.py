@@ -1,6 +1,5 @@
-"""Production meshes (task spec: single pod 16×16 = 256 chips; multi-pod
-2×16×16 = 512 chips) plus the SURF training meshes. FUNCTIONS, not
-module constants — importing this module never touches jax device state.
+"""The SURF training and serving meshes. FUNCTIONS, not module
+constants — importing this module never touches jax device state.
 
 ``make_surf_mesh(seed_shards, agent_shards)`` is the ONE axis system the
 SURF engines consume: a named ``('seed', 'agent')`` 2-D mesh whose axes
@@ -42,17 +41,6 @@ def _make_mesh(shape, axes):
     Explicit axes, whose sharding-in-types rejects e.g. a vmap over an
     agent-sharded W beside replicated batches."""
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make_mesh(shape, axes)
-
-
-def make_cpu_mesh():
-    """1-device mesh for smoke tests / benches (no XLA_FLAGS needed)."""
-    return _make_mesh((1, 1), ("data", "model"))
 
 
 def host_device_count() -> int:

@@ -1,6 +1,6 @@
 """ShapeDtypeStruct stand-ins for every model input — weak-type-correct,
 shardable, zero allocation. ``input_specs(cfg, shape)`` is the single
-source of truth the dry-run, the roofline and the launch drivers share.
+source of truth the launch drivers share.
 
 long_500k eligibility: sub-quadratic archs only (DESIGN.md §4); callers
 should consult ``shape_supported`` before lowering.

@@ -1,34 +1,34 @@
-"""Dry-run of SURF's own meta-training step on the production mesh.
+"""Collective-byte counts of compiled SURF steps, for the sharded-lane
+tests.
 
-The agent axis n (=256, power-of-two dry-run variant of the paper's n=100,
-DESIGN.md §3) shards over the data axes; the unrolled perceptron M
-(Θ(d²) params — the paper's stated size cost) is replicated per the
-divisibility fallback; graph-filter mixing S@W lowers to all-gathers over
-the agent axis — the communication pattern the §Perf pass optimizes with
-a ring ppermute variant.
+Each counter lowers one SURF step with the shardings the engine uses,
+compiles it for the current mesh and sums the collectives of its
+post-SPMD HLO (``launch.hlo_cost``):
+
+  * ``meta_step_collective_bytes`` — one agent-sharded meta step, dense
+    or through a ring/halo mixer (``tests/test_sharded_engine.py``,
+    ``tests/test_engine.py``);
+  * ``seed_meta_step_collective_bytes`` — one seed-batched meta step on
+    a 2-D ('seed', 'agent') mesh (``tests/test_mesh2d.py``);
+  * ``q_scan_collective_bytes`` — the Q-sharded scan engine per meta
+    step (``tests/test_qsharded.py``).
+
+``surf_batch_specs`` gives the batch shapes all three lower with
+(``tests/test_tasks.py``).
 """
 from __future__ import annotations
 
-import time
-import traceback
-
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import engine as TR
-from repro.configs.surf_paper import DRYRUN
-from repro.core import graph as G
 from repro.launch import hlo_cost
-from repro.launch.mesh import make_production_mesh
-from repro.launch.roofline import HBM_BW, ICI_BW, PEAK_FLOPS
 
 
 def surf_batch_specs(cfg, task=None):
     """ShapeDtypeStructs of one meta-training batch (the Xtr/Ytr/Xte/Yte
     dict every SURF lowering harness needs) — single source of truth for
-    the dry-run, the sharded-engine tests and the scan-engine bench.
+    the collective-byte counters below and the sharded-engine tests.
     ``task`` shapes the per-example feature dim and label dtype for
     non-default inner problems (``core.tasks``)."""
     from repro.core.tasks import resolve_task
@@ -128,13 +128,14 @@ def q_scan_collective_bytes(cfg, S, mesh, n_q, steps=4, eval_q=0,
     (``eval_q`` > 0 snapshots every 2 steps), and parse the post-SPMD
     HLO.  Returns (collective bytes per meta-step, per-kind dict).
 
-    THE claim ``make bench-qsharded`` asserts: with the owner-masked
-    psum select, bytes are INDEPENDENT of ``n_q`` (one dataset's bytes
-    per step), where a naive dynamic index on the sharded pool would
-    all-gather the whole pool (bytes ∝ Q).  ``naive_select=True`` keeps
-    the Q-sharded pool placement but drops back to the naive
-    ``dynamic_index_in_dim`` select — the counterfactual the bench plots
-    to show the growth the masked select removes."""
+    With the owner-masked psum select, bytes are INDEPENDENT of ``n_q``
+    (one dataset's bytes per step), where a naive dynamic index on the
+    sharded pool would all-gather the whole pool (bytes ∝ Q).
+    ``naive_select=True`` keeps the Q-sharded pool placement but drops
+    back to the naive ``dynamic_index_in_dim`` select — the
+    counterfactual that shows the growth the masked select removes.
+    ``tests/test_qsharded.py::test_q_select_collective_bytes_flat_in_q``
+    asserts both."""
     from repro.engine.core import _meta_step_core
     from repro.engine.scan import _scan_run
     from repro.engine.snapshots import make_snapshot_fn
@@ -176,111 +177,3 @@ def q_scan_collective_bytes(cfg, S, mesh, n_q, steps=4, eval_q=0,
                    S_spec if eval_q else {}).compile().as_text()
     parsed = hlo_cost.summarize(txt)
     return parsed["collective_bytes"] / steps, parsed["collectives"]
-
-
-def lower_surf_step(multi_pod: bool = False, cfg=DRYRUN, ring: bool = False,
-                    infer: bool = False, mix: str | None = None):
-    """``infer=True`` lowers the deployed unrolled optimizer (forward only,
-    the paper's inference regime) instead of the meta-training step — this
-    isolates the graph-mixing collectives the ring path optimizes from the
-    θ-gradient all-reduces that dominate meta-training.
-
-    ``mix``: None (dense S @ W), "ring" (circulant ``ppermute`` filter,
-    ring topologies only; ``ring=True`` is the legacy spelling), "halo"
-    (``topology.halo`` block-sparse exchange — works for ANY topology in
-    the config, the scenario the ring path could not cover) or
-    "halo-sched" (the TIME-VARYING composition: a link-failure schedule
-    over the config's base graph lowered through the scheduled halo
-    mixer — the step binds per-step coefficient blocks by the carried
-    ``state.step`` and keeps the ppermute exchange under time variation).
-    """
-    mesh = make_production_mesh(multi_pod=multi_pod)
-    mesh_name = "2x16x16" if multi_pod else "16x16"
-    dp = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
-    mix = mix or ("ring" if ring else None)
-    rec = {"arch": "surf-udgd" + (f"-{mix}" if mix else ""),
-           "shape": f"n{cfg.n_agents}_L{cfg.n_layers}"
-                    + ("_infer" if infer else ""),
-           "mesh": mesh_name, "chips": mesh.size, "tag": ""}
-    try:
-        A, S = G.build_topology(cfg.topology, cfg.n_agents,
-                                degree=cfg.degree, seed=0)
-        S = jnp.asarray(S, jnp.float32)
-        mix_fn = None
-        if mix == "ring":
-            from repro.core.ring import make_ring_mix
-            assert cfg.topology == "ring"
-            mix_fn = make_ring_mix(mesh, "data", cfg.n_agents,
-                                   max(1, cfg.degree // 2))
-        elif mix == "halo":
-            from repro.topology.halo import make_halo_mix
-            mix_fn = make_halo_mix(mesh, "data", np.asarray(S))
-        elif mix == "halo-sched":
-            from repro.topology.halo import make_scheduled_halo_mix
-            from repro.topology.schedule import link_failure_schedule
-            sch = link_failure_schedule(A, 50, p_fail=0.2, seed=0)
-            mix_fn = make_scheduled_halo_mix(mesh, "data", sch)
-        elif mix is not None:
-            raise ValueError(f"mix must be None|'ring'|'halo'|"
-                             f"'halo-sched', got {mix!r}")
-        if infer:
-            from repro.core import unroll as U
-
-            def step_fn(state, batch, key):
-                mf = (mix_fn.at_step(state.step)
-                      if getattr(mix_fn, "scheduled", False) else mix_fn)
-                kw, kb = jax.random.split(key)
-                W0 = U.sample_w0(kw, cfg)
-                Xl, Yl = U.sample_layer_batches(kb, batch["Xtr"],
-                                                batch["Ytr"], cfg)
-
-                def body(W, xs):
-                    p_l, Xb, Yb = xs
-                    return U.udgd_layer(p_l, S, W, Xb, Yb, cfg,
-                                        mix_fn=mf), None
-                W_L, _ = jax.lax.scan(body, W0, (state.theta, Xl, Yl))
-                return state, jnp.mean(W_L)
-        else:
-            meta_step, _ = TR.make_meta_step(cfg, S, mix_fn=mix_fn)
-            step_fn = meta_step.__wrapped__  # unjitted; re-jit w/ shardings
-
-        state_spec = jax.eval_shape(
-            lambda k: TR.init_state(k, cfg), jax.random.PRNGKey(0))
-        batch_spec = surf_batch_specs(cfg)
-        key_spec = jax.ShapeDtypeStruct((2,), jnp.uint32)
-        rep = NamedSharding(mesh, P())
-        agent_sh = NamedSharding(mesh, P(dp))
-        batch_sh = jax.tree_util.tree_map(
-            lambda l: NamedSharding(mesh, P(dp, *([None] * (l.ndim - 1)))),
-            batch_spec)
-        state_sh = jax.tree_util.tree_map(lambda l: rep, state_spec)
-
-        t0 = time.time()
-        with mesh:
-            fn = jax.jit(step_fn, in_shardings=(state_sh, batch_sh, rep),
-                         out_shardings=(state_sh, rep))
-            lowered = fn.lower(state_spec, batch_spec, key_spec)
-            compiled = lowered.compile()
-        dt = time.time() - t0
-        mem = compiled.memory_analysis()
-        parsed = hlo_cost.summarize(compiled.as_text())
-        rec.update(
-            status="ok", compile_s=round(dt, 1),
-            memory={"argument_bytes": mem.argument_size_in_bytes,
-                    "temp_bytes": mem.temp_size_in_bytes,
-                    "per_device_total": (mem.argument_size_in_bytes
-                                         + mem.temp_size_in_bytes)},
-            parsed=parsed,
-            roofline={"compute_s": parsed["flops"] / PEAK_FLOPS,
-                      "memory_s": parsed["bytes"] / HBM_BW,
-                      "collective_s": parsed["collective_bytes"] / ICI_BW,
-                      "dominant": max(
-                          (("compute", parsed["flops"] / PEAK_FLOPS),
-                           ("memory", parsed["bytes"] / HBM_BW),
-                           ("collective",
-                            parsed["collective_bytes"] / ICI_BW)),
-                          key=lambda kv: kv[1])[0]})
-    except Exception as e:  # noqa: BLE001
-        rec.update(status="error", error=f"{type(e).__name__}: {e}",
-                   trace=traceback.format_exc()[-2000:])
-    return rec

@@ -1,9 +1,7 @@
 """End-to-end LM training driver (example application + integration proof).
 
-Trains any ``--arch`` (reduced variant by default — the full configs are
-exercised via dryrun.py) on the synthetic token pipeline for N steps with
-checkpointing. On real hardware the same driver runs the full config on
-the production mesh (--mesh prod).
+Trains any ``--arch`` (reduced variant by default) on the synthetic token
+pipeline for N steps with checkpointing.
 
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-4b --steps 50
 """

@@ -23,8 +23,8 @@ bucketing + provably-inert padding, on the device), ``queue``
 (continuous batching of device-resident slots + futures,
 deadline-aware admission), ``driver`` (``AsyncDriver`` — a
 background tick thread so ``submit`` returns immediately), ``metrics``
-(throughput/latency/pad-waste/cache telemetry).  The CLI driver is
-``repro.launch.surf_serve``.
+(throughput/latency/pad-waste/cache telemetry).  The benchmark's
+serving cells drive it from ``bench/``.
 
 Where a tick's time goes: run the server under a profiler session,
 

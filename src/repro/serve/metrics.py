@@ -2,8 +2,8 @@
 and pad waste.
 
 ``ServeMetrics`` accumulates one record per completed request and one
-per solver tick; ``summary()`` condenses them into the numbers
-``launch.surf_serve`` stamps into ``BENCH_serve.json``:
+per solver tick; ``summary()`` condenses them into these numbers
+(``tests/test_serve.py::test_metrics_summary_fields`` checks them):
 
   * ``federations_per_sec`` — completed requests over wall time, from
     the earliest submit of a completed request to the last completion;

@@ -453,8 +453,9 @@ class FederationServer:
         ``take`` program, run once on a batch of such a slot under
         all-false masks, through the same path as a tick (identical jit
         signatures to real traffic — exactly ONE solver body trace per
-        bucket, which ``launch.surf_serve`` asserts).  Returns the warmed
-        buckets."""
+        bucket, which ``tests/test_serve.py`` asserts in
+        ``test_trace_count_one_per_warm_bucket_zero_at_request_rate``).
+        Returns the warmed buckets."""
         ydt = self.task.label_dtype
         F, m = self.task.feat_dim, int(self.cfg.train_per_agent)
         slots = {}

@@ -14,8 +14,8 @@ AXIS ROLES, not axis names: every rule shards one of three roles —
 
 ``axis_for_role`` maps a role to the mesh axis that carries it: the
 named ``'seed'``/``'agent'`` axes of a ``launch.mesh.make_surf_mesh``
-2-D mesh, or the legacy ``'data'`` axis on the 1-D shim meshes
-(``make_agent_mesh`` / the production ('data', 'model') meshes), where
+2-D mesh, or the legacy ``'data'`` axis on the 1-D shim mesh
+(``make_agent_mesh``, a ('data', 'model') mesh), where
 BOTH roles degrade onto the single sharded axis and each engine uses
 the one role it shards. Rules compose as pytree prefixes and default to
 role resolution when no explicit axis is passed, so one rule set serves
@@ -189,15 +189,16 @@ def make_q_select(mesh: Mesh, axis):
     INDEPENDENT of Q.
 
     A plain ``dynamic_index_in_dim`` on a dim-0-sharded pool makes the
-    SPMD partitioner all-gather the WHOLE pool every step (bytes ∝ Q —
-    measured, see BENCH_qsharded.json). Instead each shard slices its
-    LOCAL block at ``(t % n_q) % q_local``, masks the slice to zero unless
-    it owns dataset ``t % n_q``, and a ``psum`` over the Q-carrying axis
-    re-assembles exactly one dataset: one all-reduce of ONE dataset's
-    bytes per step, whatever Q is. The masked sum adds exact zeros, so
-    the selected batch is BIT-equal to the replicated index. ``n_q`` is
-    derived from the local block (global dim 0 = local · shards), so one
-    select serves every pool size."""
+    SPMD partitioner all-gather the WHOLE pool every step (bytes ∝ Q;
+    ``tests/test_qsharded.py::test_q_select_collective_bytes_flat_in_q``
+    asserts the growth and this select's flat bytes). Instead each shard
+    slices its LOCAL block at ``(t % n_q) % q_local``, masks the slice to
+    zero unless it owns dataset ``t % n_q``, and a ``psum`` over the
+    Q-carrying axis re-assembles exactly one dataset: one all-reduce of
+    ONE dataset's bytes per step, whatever Q is. The masked sum adds
+    exact zeros, so the selected batch is BIT-equal to the replicated
+    index. ``n_q`` is derived from the local block (global dim 0 =
+    local · shards), so one select serves every pool size."""
     n_shards = int(mesh.shape[axis])
 
     def select(stacked, t):

@@ -2,7 +2,7 @@ import os
 import sys
 
 # Tests run on the single real CPU device by default — the N-device trick is
-# for launch/dryrun.py (task spec) and for the OPT-IN sharded lane
+# for the OPT-IN sharded lane
 # (`make test-sharded` sets REPRO_SHARDED_LANE=1 together with
 # XLA_FLAGS=--xla_force_host_platform_device_count=8 so the ring ppermute
 # path runs with nshards > 1; see tests/test_sharded_engine.py). Keep any

@@ -50,7 +50,7 @@ def test_dgd_consensus_effect(setup):
                      lr=0.5)
     # re-run manually to capture final W disagreement via loss proxy:
     # after many rounds the loss std across agents shrinks vs W0.
-    from repro.core import task as T
+    from repro.core.tasks import classification as T
     l0 = jax.vmap(T.local_loss, (0, 0, 0, None, None))(
         W0, batch["Xte"], batch["Yte"], CFG.feature_dim, CFG.n_classes)
     assert float(jnp.std(l0)) >= 0  # sanity anchor

@@ -1,7 +1,8 @@
 """The unified streaming engine (``repro.engine``): seed-batched training
 parity with sequential runs, in-scan evaluation snapshots vs offline
 recomputation, donate-through-checkpoint bit-exact resume, checkpoint-io
-hardening, the scheduled halo mixer, and the compat shim.
+hardening, the scheduled halo mixer, and the lazy ``repro.core.surf``
+attribute.
 
 Multi-device tests (seed-axis-sharded engine, 8-shard scheduled halo)
 carry the same skip marker as ``tests/test_sharded_engine.py`` and run in
@@ -402,16 +403,10 @@ def test_scheduled_halo_resumes_mid_schedule(mds, tmp_path):
     _assert_trees_close(ref, resumed, atol=1e-6, rtol=1e-6)
 
 
-# ------------------------------------------------------- compat shim
-def test_trainer_shim_reexports_engine():
-    from repro.core import trainer as TR
-    assert TR.train_scan is E.train_scan
-    assert TR.make_train_scan is E.make_train_scan
-    assert TR.TRACE_COUNTS is E.TRACE_COUNTS
-    assert TR._ENGINE_CACHE is E._ENGINE_CACHE
-    # lazy submodules stay reachable as package ATTRIBUTES too (PEP 562)
+# ------------------------------------------------- lazy core attribute
+def test_core_package_resolves_surf_lazily():
+    # the lazy submodule stays reachable as a package ATTRIBUTE (PEP 562)
     import repro.core
-    assert repro.core.trainer is TR
     assert repro.core.surf is surf
     with pytest.raises(AttributeError):
         repro.core.nonexistent

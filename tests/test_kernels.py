@@ -7,8 +7,7 @@ import pytest
 
 from repro.core import unroll
 from repro.kernels.flash_attention import attention_ref, flash_attention
-from repro.kernels.graph_filter import (graph_filter, graph_filter_hsw,
-                                        graph_filter_ref)
+from repro.kernels.graph_filter import graph_filter, graph_filter_ref
 from repro.kernels.graph_filter.ops import pallas_profitable, pick_block_d
 from repro.kernels.ssm_scan import wkv, wkv_ref
 
@@ -92,13 +91,6 @@ def test_graph_filter_block_d_invariance():
     y_128 = graph_filter(S, W, h, impl="pallas", block_d=128)
     assert pick_block_d(33, 300) in (128, 256)
     np.testing.assert_allclose(y_auto, y_128, atol=1e-6)
-
-
-def test_graph_filter_hsw_alias():
-    """Deprecated (h, S, W)-order alias forwards to the unified API."""
-    S, W, h = _gf_inputs(16, 24, 2)
-    np.testing.assert_allclose(graph_filter_hsw(h, S, W),
-                               graph_filter(S, W, h), atol=0)
 
 
 # --------------------------------------------------------- flash attention

@@ -9,10 +9,10 @@ from hypothesis import given, settings, strategies as st  # noqa: E402
 
 from repro.configs.base import MoEConfig, SURFConfig
 from repro.core import constraints as C
-from repro.core import graph as G
 from repro.core import unroll as U
 from repro.models import layers as L
 from repro.models import moe as MO
+from repro.topology import families as G
 
 SET = dict(max_examples=15, deadline=None)
 

@@ -79,6 +79,32 @@ def test_qsharded_train_matches_replicated(pools):
 
 
 @multi_device
+def test_q_select_collective_bytes_flat_in_q():
+    """Per-meta-step collective bytes of the Q-sharded scan engine stay
+    flat from Q to 2Q to 4Q (the owner-masked psum select moves one
+    dataset a step), while the naive dynamic-index select on the same
+    sharded pool all-gathers the pool and grows with Q."""
+    from repro.launch.surf_dryrun import q_scan_collective_bytes
+    cfg = SURFConfig(n_agents=32, n_layers=4, filter_taps=2, feature_dim=16,
+                     n_classes=8, batch_per_agent=6, train_per_agent=12,
+                     test_per_agent=6, eps=0.05, topology="ring", degree=2)
+    q, eval_q, agent_shards = 16, 8, 8
+    mesh = make_surf_mesh(1, agent_shards, n_agents=cfg.n_agents)
+    _, S = surf.make_problem(cfg, seed=0)
+    masked, naive = [], []
+    for n_q in (q, 2 * q, 4 * q):
+        masked.append(q_scan_collective_bytes(cfg, S, mesh, n_q, steps=4,
+                                              eval_q=eval_q)[0])
+        naive.append(q_scan_collective_bytes(cfg, S, mesh, n_q, steps=4,
+                                             eval_q=eval_q,
+                                             naive_select=True)[0])
+    assert masked[0] > 0 and naive[0] > 0
+    growth = masked[-1] / masked[0]
+    assert growth <= 1.05, masked
+    assert naive[-1] / naive[0] > growth, naive
+
+
+@multi_device
 def test_qsharded_seed_engine_2d_mesh(pools):
     """Seed-batched engine on a (seed=2, agent=4) mesh with the pool AND
     eval stack Q-sharded over the agent sub-axis: per-seed rows match

@@ -6,12 +6,11 @@ import pytest
 
 from repro.configs.surf_paper import SMOKE
 from repro.core import constraints as C
-from repro.core import graph as G
 from repro.core import surf
-from repro.core import task as T
 from repro import engine as TR
 from repro.core import unroll as U
 from repro.data import synthetic
+from repro.topology import families as G
 
 CFG = SMOKE
 

@@ -21,12 +21,12 @@ from repro.configs.surf_paper import SMOKE, SPARSE_SMOKE
 from repro.core import baselines as B
 from repro.core import constraints as C
 from repro.core import surf
-from repro.core import task as T
 from repro.core import unroll as U
 from repro.core.tasks import (ClassificationTask, SparseRecoveryTask,
                               classification_task, resolve_task,
                               signal_nmse, soft_threshold,
                               sparse_recovery_task, support_f1)
+from repro.core.tasks import classification as T
 from repro.data import synthetic
 from repro.launch.mesh import host_device_count
 from repro.launch.surf_dryrun import surf_batch_specs
@@ -335,13 +335,6 @@ def test_surf_batch_specs_are_task_aware():
     spec_s = surf_batch_specs(SCFG)
     assert spec_s["Xtr"].shape[-1] == SCFG.task.signal_dim
     assert spec_s["Ytr"].dtype == jnp.float32
-
-
-def test_compat_shim_exports_legacy_api():
-    for name in ("head_dim", "unflatten", "local_loss", "local_accuracy",
-                 "fl_loss", "fl_accuracy", "fl_grad", "grad_norm",
-                 "features_from_backbone"):
-        assert hasattr(T, name)
 
 
 def test_async_eval_runs_task_aware(sparse_mds):
