@@ -227,12 +227,12 @@ def served_sharding(server, bucket, request):
     checked), run as ``warm()`` runs it, on a batch assembled from
     ``request``'s device slot under all-false masks."""
     misses = server.cache_stats()["misses"]
-    solve = server._solver(bucket)
+    solve = server._solver(bucket, server.max_batch)
     if server.cache_stats()["misses"] != misses:
         fail(f"the server built a new executable for {bucket}")
     slot = server._slot(request["S"], request["ds"], bucket,
                         request["seed"], 0)
-    args, mask, t_real = server._batch(bucket, [slot])
+    args, mask, t_real = server._batch(bucket, server.max_batch, [slot])
     out = solve(args[0], server.theta, *args[1:], mask, t_real)
     return out["final_loss"].sharding
 

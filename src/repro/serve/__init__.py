@@ -5,8 +5,9 @@ SURF's trained unrolled network solves an unseen federation in one
 forward pass (amortization, paper §4).  This package operationalizes
 that: requests (a mixing matrix + a cohort dataset) are featurized at
 their true shape, padded into shape buckets, continuously batched and
-solved through per-bucket compiled executables — one trace per bucket,
-zero at request rate.
+solved through per-bucket compiled executables, one a rung of batch
+sizes (1, 2, 4, …, ``max_batch``; a tick runs the smallest that holds its
+requests) — one trace per (bucket, rung), zero at request rate.
 
     server = FederationServer(cfg, state.theta, mix="pallas")
     server.warm([(n, t), ...])           # compile ahead of traffic
@@ -38,8 +39,9 @@ inside it, and each ``tick`` leaves ``serve.tick`` (``reqs``, the ids it
 admitted; ``bucket``) with ``serve.tick.admit``, ``.stack``, ``.call``
 (``bytes_in``, host bytes handed to the solver: the slots' masks and
 ``t_real``, since request data is uploaded once, at ``submit``;
-``devices``, ``theta_bytes`` and ``gather_bytes``, what each device of
-the mesh streams and receives),
+``slots``, the rung's batch slots the executable ran; ``devices``,
+``theta_bytes`` and ``gather_bytes``, what each device of the mesh
+streams and receives),
 ``.wait`` and ``.unpack`` (one transfer of the outputs, then the
 per-request split) inside it.  Each is a record in ``recs`` (start, end,
 thread CPU seconds, parent) and a ``surf.*`` host event in the profile,

@@ -17,11 +17,16 @@ Semantics:
 
   * DETERMINISM — the driver adds no scheduling of its own: it just
     calls ``server.tick()``, so admission order (deadline → aging →
-    fullest bucket, FIFO within bucket) and per-request results are
-    IDENTICAL to a manual tick loop over the same submission order
-    (padding is provably inert, so results never depend on batch
-    composition).  Queue mutations are guarded by the server's lock;
-    submits landing mid-tick simply ride the next tick.
+    fullest bucket, FIFO within bucket) follows the server's policy,
+    and on a queue filled before the loop starts the ticks and
+    per-request results are IDENTICAL to a manual tick loop. Padding is
+    provably inert, so within a batch size results never depend on
+    batch composition; but a tick runs the smallest rung that holds
+    what it admits, so where submits land while the loop ticks, a
+    request may run at another rung than in a manual loop, and its
+    results then agree with that loop's to within the rungs' rounding
+    (the last bits), not bitwise. Queue mutations are guarded by the
+    server's lock; submits landing mid-tick simply ride the next tick.
   * CADENCE — ``interval_s`` sleeps between NON-EMPTY polls; an empty
     queue parks the thread on a condition variable until the next
     submit (no busy-wait), so an idle driver costs nothing.

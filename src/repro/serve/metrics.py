@@ -10,8 +10,12 @@ per solver tick; ``summary()`` condenses them into these numbers
   * ``latency_p50_ms`` / ``latency_p99_ms`` — from ``submit``'s entry to
     completion, so featurization and queueing count, exactly what a
     caller observes;
-  * ``occupancy`` — admitted requests over offered batch slots (low
-    occupancy = the stream is too fragmented for ``max_batch``);
+  * ``occupancy`` — admitted requests over the batch slots the ticks ran
+    (each tick runs the smallest rung of the server's ladder that holds
+    its requests, so empty slots are those of the rung, not of
+    ``max_batch``);
+  * ``ticks_by_slots`` — {batch slots run: ticks}, how often each rung
+    ran;
   * ``pad_waste`` — 1 − useful/padded compute cells, where a cell is
     one (agent × test-row) unit; waste comes from bucket rounding AND
     empty batch slots;
@@ -44,11 +48,12 @@ class ServeMetrics:
         self.solve_time = 0.0            # seconds inside solver calls
         self.first_submit = None         # perf_counter, earliest completed
         self.last_done = None            # perf_counter, latest completion
-        self.slots_offered = 0           # max_batch per tick
+        self.slots_offered = 0           # Σ the rung's slots over ticks
         self.admitted = 0
         self.useful_cells = 0.0          # Σ n_real * t_real over requests
         self.padded_cells = 0.0          # Σ slots * n_pad * t_pad over ticks
         self.per_bucket = {}             # bucket -> tick count
+        self.ticks_by_slots = {}         # rung's slots -> tick count
         self.depth_hist = {}             # realized depth -> request count
         self.layers_run = 0              # Σ while-loop trips over ticks
         self.adaptive_ticks = 0
@@ -67,7 +72,9 @@ class ServeMetrics:
         self.ticks += 1
         self.completed += int(n_admitted)
         self.admitted += int(n_admitted)
-        self.slots_offered += int(slots)
+        slots = int(slots)
+        self.slots_offered += slots
+        self.ticks_by_slots[slots] = self.ticks_by_slots.get(slots, 0) + 1
         self.solve_time += float(wall)
         self.useful_cells += float(useful_cells)
         self.padded_cells += float(padded_cells)
@@ -109,6 +116,7 @@ class ServeMetrics:
             "per_bucket_ticks": {f"n{n}xt{t}": c
                                  for (n, t), c in
                                  sorted(self.per_bucket.items())},
+            "ticks_by_slots": dict(sorted(self.ticks_by_slots.items())),
         }
         if self.cache is not None:
             out["bucket_cache"] = dict(self.cache.stats())

@@ -6,10 +6,12 @@ mixing matrix + dataset), featurizes it at its true shape and pads it
 into its shape bucket on the device (``_sample_and_pad``), and enqueues
 the padded slot without waiting for the chip; ``tick()`` admits up to
 ``max_batch`` bucket-compatible requests FIFO-first, stacks their device
-slots into the bucket's fixed ``(B, n_pad, ...)`` batch with one jitted
-``assemble`` program (empty slots repeat the first slot and are masked
-out, so the executable never sees a new batch size), solves them in
-one jitted call and brings to the host only what the admitted requests'
+slots into a ``(B, n_pad, ...)`` batch with one jitted ``assemble``
+program, where B is the smallest rung of the server's fixed ladder
+(``solver.batch_rungs``: 1, 2, 4, …, ``max_batch``) that holds them (empty
+slots up to B repeat the first slot and are masked out, so the
+executables only ever see the ladder's batch sizes), solves them in one
+jitted call and brings to the host only what the admitted requests'
 futures hold: each admitted slot's W, its real columns, as one fully
 replicated array from a jitted ``take`` program (one transfer from one
 device, whatever the layout; the empty slots' W never leaves the
@@ -47,11 +49,12 @@ and caller submits interleave safely.
 padded convergence-probe split, results gain a realized ``depth``, and
 ``metrics.summary()`` grows a depth histogram + FLOPs-saved estimates.
 
-Everything expensive is cached: one executable per (bucket, B, mix,
+Everything expensive is cached: one executable per (bucket, rung, mix,
 task) in a per-server ``BoundedLRU`` (registered as "serve-buckets" for
-``repro.clear_caches()``), beside one pad program per (true shape,
-bucket), one ``assemble`` program per bucket shape and one ``take``
-program per bucket shape, all warmed ahead of traffic with ``warm()``.
+``repro.clear_caches()``; ``max_buckets`` buckets of every rung), beside
+one pad program per (true shape, bucket), one ``assemble`` program and one
+``take`` program per (bucket, rung) shape, all warmed ahead of traffic
+with ``warm()``.
 """
 from __future__ import annotations
 
@@ -71,7 +74,8 @@ from repro.core import unroll as U
 from repro.core.tasks import resolve_task
 from repro.serve.buckets import BucketSpec, pad_cohort, pad_probe, slot_mask
 from repro.serve.metrics import ServeMetrics
-from repro.serve.solver import (make_bucket_solver, request_shardings,
+from repro.serve.solver import (batch_rungs, make_bucket_solver,
+                                request_shardings, request_shards,
                                 resolve_serve_mix, slot_shardings,
                                 tick_bytes)
 from repro.sharding.surf_rules import (padded_columns, place_theta,
@@ -156,8 +160,8 @@ class FederationServer:
         if mesh is not None:
             # fail at construction, not at the first tick: the request
             # axis must split evenly over the mesh (ragged TRAFFIC is
-            # fine — masked empty slots — but the bucket batch shape
-            # is fixed)
+            # fine — masked empty slots up to the rung — but every rung,
+            # max_batch the largest, is a fixed batch shape)
             in_sh, _ = request_shardings(mesh, int(max_batch), depth)
             stack_out = (in_sh[0],) + in_sh[2:-2]
             slot_out = slot_shardings(mesh, depth)
@@ -173,14 +177,21 @@ class FederationServer:
         self.max_batch = int(max_batch)
         self.mesh = mesh
         self.devices = 1 if mesh is None else int(mesh.size)
-        self._cache = BoundedLRU(maxsize=max_buckets, name="serve-buckets")
+        # the batch sizes a tick runs at: the smallest that holds the
+        # admitted requests, so a lone request does not pay for
+        # ``max_batch`` slots
+        self.rungs = batch_rungs(self.max_batch, request_shards(mesh))
+        self._cache = BoundedLRU(maxsize=max_buckets * len(self.rungs),
+                                 name="serve-buckets")
         # one program per (true shape, bucket) draws and pads a slot, and
-        # one per bucket shape stacks B device slots into the solver's
-        # (B, ...) arguments, each laid out as the solver takes them
+        # one per (bucket, rung) shape stacks B device slots into the
+        # solver's (B, ...) arguments, each laid out as the solver takes
+        # them
         self._pad = _pad_program(slot_out)
         self._assemble = jax.jit(_stack_slots, **(
             {} if mesh is None else {"out_shardings": stack_out}))
-        # and one per bucket shape takes slot i's W, its real columns, as
+        # and one per (bucket, rung) shape takes slot i's W, its real
+        # columns, as
         # one array replicated on every device, so it comes back in one
         # transfer from one device (``_fetch``)
         self._take = jax.jit(
@@ -283,25 +294,28 @@ class FederationServer:
             return len(self._queue)
 
     # ------------------------------------------------------------ solve
-    def _solver(self, bucket):
-        return make_bucket_solver(self.cfg, bucket, self.max_batch,
+    def _rung(self, k):
+        """The smallest rung of ``self.rungs`` that holds ``k`` requests."""
+        return next(b for b in self.rungs if b >= k)
+
+    def _solver(self, bucket, B):
+        return make_bucket_solver(self.cfg, bucket, B,
                                   activation=self.activation,
                                   mix_fn=self.mix_fn, task=self.task,
                                   cache=self._cache, depth=self.depth,
                                   mesh=self.mesh)
 
-    def _batch(self, bucket, slots, masks=(), t_reals=()):
-        """The solver's arguments for the device ``slots`` (one or more)
+    def _batch(self, bucket, B, slots, masks=(), t_reals=()):
+        """The solver's arguments for the device ``slots`` (one to ``B``)
         of ``bucket``, the first ``len(masks)`` of them admitted: the
-        ``assemble`` program stacks them into ``max_batch`` slots, each
-        missing one a repeat of the first slot, and every slot past the
+        ``assemble`` program stacks them into ``B`` slots, each missing
+        one a repeat of the first slot, and every slot past the
         admitted ones gets an all-false mask and ``t_real = t_pad`` —
         masked agents never reach a result (the padded-loss corrections
         stay on their identity branch; adaptive slots start INACTIVE,
         depth 0), and an empty slot's result is never read — so empty
         slots cost no device memory of their own.  ``mask`` (B, n_pad)
         and ``t_real`` (B,) are host arrays."""
-        B = self.max_batch
         stacked = self._assemble(list(slots)
                                  + [slots[0]] * (B - len(slots)))
         e_mask, e_t = slot_mask(0, int(bucket.rows), bucket)
@@ -365,7 +379,9 @@ class FederationServer:
     def tick(self) -> int:
         """One continuous-batching step: pick a bucket
         (``_select_bucket``), admit up to ``max_batch`` of its requests
-        FIFO-within-bucket, solve, complete their futures.  Passed-over
+        FIFO-within-bucket, solve them in the smallest rung that holds
+        them (``_rung``; the ``serve.tick.call`` span's ``slots``),
+        complete their futures.  Passed-over
         requests age by one tick.  Returns the number of requests
         completed (0 on an empty queue).  Bucket selection and admission
         run under the server lock (an async driver may tick while
@@ -392,20 +408,21 @@ class FederationServer:
                 self._queue = rest
             tick_span.set(reqs=[r.rid for r in admitted],
                           bucket=tuple(bucket))
+            B = self._rung(len(admitted))
             with spans.span("serve.tick.stack"):
                 stacked, mask, t_real = self._batch(
-                    bucket, *zip(*[(r.arrays, r.mask, r.t_real)
+                    bucket, B, *zip(*[(r.arrays, r.mask, r.t_real)
                                    for r in admitted]))
                 for r in admitted:      # free each slot once stacked
                     r.arrays = None
-            solve = self._solver(bucket)
-            theta_b, gather_b = tick_bytes(self.cfg, bucket, self.max_batch,
-                                           self.mesh, self.task)
+            solve = self._solver(bucket, B)
+            theta_b, gather_b = tick_bytes(self.cfg, bucket, B, self.mesh,
+                                           self.task)
             t0 = time.perf_counter()
             with spans.span("serve.tick.call",
                             bytes_in=mask.nbytes + t_real.nbytes,
-                            devices=self.devices, theta_bytes=theta_b,
-                            gather_bytes=gather_b):
+                            devices=self.devices, slots=B,
+                            theta_bytes=theta_b, gather_bytes=gather_b):
                 out = solve(stacked[0], self.theta, *stacked[1:], mask,
                             t_real)
             del stacked
@@ -424,14 +441,14 @@ class FederationServer:
                     lats.append(lat)
                 unpack_span.set(bytes_out=nbytes)
             useful = sum(r.n_real * r.rows_real for r in admitted)
-            padded = self.max_batch * int(bucket.n_agents) * int(bucket.rows)
+            padded = B * int(bucket.n_agents) * int(bucket.rows)
             kw = {}
             if self.depth == "adaptive":
                 depths = [int(d) for d in host["depth"][:len(admitted)]]
                 kw = {"depths": depths,
                       "layers_run": max(depths, default=0),
                       "n_layers": self.cfg.n_layers}
-            self.metrics.record_tick(bucket, len(admitted), self.max_batch,
+            self.metrics.record_tick(bucket, len(admitted), B,
                                      useful, padded, lats, wall, done_at=now,
                                      **kw)
             return len(admitted)
@@ -449,11 +466,12 @@ class FederationServer:
         (n_agents, test_rows) pairs.  Each pair gets its featurization
         and pad program built by padding an all-zero request of that
         true shape (``cfg.train_per_agent`` training rows); each distinct
-        bucket they map to gets its ``assemble`` program, executable and
-        ``take`` program, run once on a batch of such a slot under
-        all-false masks, through the same path as a tick (identical jit
-        signatures to real traffic — exactly ONE solver body trace per
-        bucket, which ``tests/test_serve.py`` asserts in
+        bucket they map to gets, at every rung of ``self.rungs``, its
+        ``assemble`` program, executable and ``take`` program, run once on
+        a batch of such a slot under all-false masks, through the same
+        path as a tick (identical jit signatures to real traffic — exactly
+        ONE solver body trace per (bucket, rung), which
+        ``tests/test_serve.py`` asserts in
         ``test_trace_count_one_per_warm_bucket_zero_at_request_rate``).
         Returns the warmed buckets."""
         ydt = self.task.label_dtype
@@ -469,10 +487,11 @@ class FederationServer:
                               0, 0)
             slots.setdefault(bucket, slot)
         for bucket, slot in slots.items():
-            stacked, mask, t_real = self._batch(bucket, [slot])
-            out = self._solver(bucket)(stacked[0], self.theta, *stacked[1:],
-                                       mask, t_real)
-            self._fetch(out, 1)
+            for B in self.rungs:
+                stacked, mask, t_real = self._batch(bucket, B, [slot])
+                out = self._solver(bucket, B)(stacked[0], self.theta,
+                                              *stacked[1:], mask, t_real)
+                self._fetch(out, 1)
         return list(slots)
 
     def cache_stats(self) -> dict:

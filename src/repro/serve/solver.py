@@ -31,8 +31,10 @@ gathered before the first layer and each layer's output at its end, where
 the layer's loss and accuracy read it too.
 
 The per-bucket executable cache key extends ``engine._engine_cache_key``
-with the bucket dims; ``engine.TRACE_COUNTS["serve"]`` counts body
-traces (the bench asserts one per warm bucket, zero at request rate).
+with the bucket dims and the batch size B; a server builds one executable
+a rung of its ``batch_rungs`` and runs the smallest that holds a tick's
+admitted requests. ``engine.TRACE_COUNTS["serve"]`` counts body traces
+(the tests assert one per warmed (bucket, rung), zero at request rate).
 """
 from __future__ import annotations
 
@@ -241,6 +243,27 @@ def serve_cache_key(cfg: SURFConfig, bucket, max_batch, activation,
                                 mesh=mesh, mix_fn=mix_fn, task=task)
 
 
+def request_shards(mesh):
+    """How many blocks the request axis splits into on ``mesh``: the size
+    of its agent-role axis (1 without a mesh)."""
+    if mesh is None:
+        return 1
+    return _axis_size(mesh, axis_for_role(mesh, "agent"))
+
+
+def batch_rungs(max_batch, shards):
+    """The batch sizes a server runs its bucket executables at: ``shards``
+    × 2^j below ``max_batch``, then ``max_batch`` itself — (1, 2, 4, 8) for
+    ``max_batch=8`` on one device, (4, 8) with the request axis split 4
+    ways, (1, 2, 3) for ``max_batch=3``. Every rung divides evenly over
+    the ``shards`` blocks, since ``max_batch`` must."""
+    rungs, b = [], int(shards)
+    while b < max_batch:
+        rungs.append(b)
+        b *= 2
+    return tuple(rungs) + (int(max_batch),)
+
+
 def request_shardings(mesh, max_batch, depth="fixed"):
     """(in_shardings, out_shardings) for a bucket solver on ``mesh``: the
     REQUEST axis (leading B on every arg and output) shards over the
@@ -255,7 +278,7 @@ def request_shardings(mesh, max_batch, depth="fixed"):
     shard count — ragged tails already ride as masked empty slots, so
     the constraint is on the BUCKET batch shape, not on traffic."""
     axis = axis_for_role(mesh, "agent")
-    shards = _axis_size(mesh, axis)
+    shards = request_shards(mesh)
     check_divides(max_batch, shards, "the sharded serve batch",
                   "max_batch",
                   "each device solves an equal block of request slots "
@@ -294,20 +317,20 @@ def slot_shardings(mesh, depth="fixed"):
 
 
 @functools.lru_cache(maxsize=64)
-def tick_bytes(cfg: SURFConfig, bucket, max_batch, mesh=None, task=None):
-    """(theta_bytes, gather_bytes) of one call of a bucket executable, per
-    device, from the shapes: the bytes of θ's float32 block the device
-    streams through its layers, and the bytes it receives from the
-    all-gathers of W's columns (L + 1 of them, for its block of request
-    slots; 0 without a θ split). Counted once a (bucket, layout)."""
+def tick_bytes(cfg: SURFConfig, bucket, batch, mesh=None, task=None):
+    """(theta_bytes, gather_bytes) of one call of a bucket executable of
+    ``batch`` request slots, per device, from the shapes: the bytes of
+    θ's float32 block the device streams through its layers, whatever
+    ``batch`` is, and the bytes it receives from the all-gathers of W's
+    columns (L + 1 of them, for its block of the ``batch`` slots; 0
+    without a θ split). Counted once a (bucket, batch, layout)."""
     task = resolve_task(cfg, task)
     split = theta_split(mesh)
     cols = padded_columns(task.dim, split)
     din = U.perceptron_in_dim(cfg, task)
     L_ = cfg.n_layers
     theta = 4 * L_ * ((din + 1) * cols // split + cfg.filter_taps + 1)
-    slots = max_batch // (1 if mesh is None else
-                          _axis_size(mesh, axis_for_role(mesh, "agent")))
+    slots = batch // request_shards(mesh)
     gather = (4 * (L_ + 1) * slots * int(bucket.n_agents) * cols
               * (split - 1) // split)
     return theta, gather
@@ -316,7 +339,9 @@ def tick_bytes(cfg: SURFConfig, bucket, max_batch, mesh=None, task=None):
 def make_bucket_solver(cfg: SURFConfig, bucket, max_batch, *,
                        activation="relu", mix_fn=None, task=None,
                        cache=None, depth="fixed", mesh=None):
-    """The jitted request-batched solver for one shape bucket.
+    """The jitted request-batched solver for one shape bucket at a batch
+    of ``max_batch`` request slots (a server builds one a rung of its
+    ``batch_rungs``).
 
     ``depth="fixed"`` (default): vmap-of-scan ``solve(S (B,n,n), theta,
     W0 (B,n,d), Xl (B,L,n,b,F), Yl (B,L,n,b), Xte (B,n,t,F),

@@ -224,17 +224,21 @@ def test_fifo_head_defines_tick_bucket(trained):
 
 
 def test_trace_count_one_per_warm_bucket_zero_at_request_rate(trained):
+    """``warm()`` traces the solver once a (bucket, rung); ticks of any
+    admitted count then trace nothing."""
     state, _ = trained
     srv = _server(state.theta)
+    assert srv.rungs == (1, 2, 4)
     base = E.TRACE_COUNTS["serve"]
     warmed = srv.warm([(6, 4), (8, 4), (12, 4)])   # -> buckets (8,4),(16,4)
     assert len(warmed) == 2
-    assert E.TRACE_COUNTS["serve"] - base == 2
-    for i, n in enumerate([6, 8, 12, 16, 10]):
+    assert E.TRACE_COUNTS["serve"] - base == 2 * 3
+    for i, n in enumerate([6, 8, 12, 16, 10, 6, 8, 6, 8]):
         _, S, ds = _cohort(n, 4, seed=40 + i)
         srv.submit(S, ds, seed=i)
-    srv.drain()
-    assert E.TRACE_COUNTS["serve"] - base == 2     # zero replay traces
+    srv.drain()                # (8,4): 6 requests, rungs 4 and 2; (16,4): 3
+    assert srv.metrics.ticks_by_slots == {2: 1, 4: 2}
+    assert E.TRACE_COUNTS["serve"] - base == 2 * 3  # zero replay traces
 
 
 # --------------------------------------------- device-resident slots
@@ -288,44 +292,49 @@ KS = (1, 3, 8)
 @pytest.fixture(scope="module")
 def ticked(trained, tmp_path_factory):
     """One server (``max_batch=8``) answering one tick of each ``k`` in
-    ``KS`` under a profiler session: {k: (requests, futures, the same
-    executable's output on the host-stacked numpy batch of the same
-    slots, zero-filled past the ``k`` admitted, the tick's
-    ``serve.tick.unpack`` record)}."""
+    ``KS`` under a profiler session: {k: (requests, futures, the output
+    of the executable of the rung the tick runs (1, 4, 8) on the
+    host-stacked numpy batch of the same slots, zero-filled past the
+    ``k`` admitted, the tick's ``serve.tick.call`` and
+    ``serve.tick.unpack`` records)}."""
     state, _ = trained
     srv = _server(state.theta, max_batch=8)
-    solve = srv._solver(Bucket(8, 4))
     ticks = {}
     with jax.profiler.trace(str(tmp_path_factory.mktemp("ticked"))):
-        for k in KS:
+        for k, B in zip(KS, (1, 4, 8)):
             futs = [srv.submit(*_cohort([6, 8][i % 2], 4, seed=100 + i)[1:],
                                seed=i) for i in range(k)]
             reqs = list(srv._queue)
             slots = [[np.asarray(a) for a in r.arrays] for r in reqs]
-            slots += [[np.zeros_like(a) for a in slots[0]]] * (8 - k)
+            slots += [[np.zeros_like(a) for a in slots[0]]] * (B - k)
             args = [np.stack(a) for a in zip(*slots)]
             mask = np.stack([r.mask for r in reqs]
-                            + [np.zeros(8, bool)] * (8 - k))
-            t_real = np.array([r.t_real for r in reqs] + [4.0] * (8 - k),
+                            + [np.zeros(8, bool)] * (B - k))
+            t_real = np.array([r.t_real for r in reqs] + [4.0] * (B - k),
                               np.float32)
+            solve = srv._solver(Bucket(8, 4), B)
             ref = jax.device_get(solve(args[0], state.theta, *args[1:],
                                        mask, t_real))
             t0 = time.perf_counter()
             assert srv.tick() == k
-            (unpack,) = [r for r in spans.records()
-                         if r.t0 >= t0 and r.name == "serve.tick.unpack"]
-            ticks[k] = reqs, futs, ref, unpack
+            call, unpack = ([r for r in spans.records()
+                             if r.t0 >= t0 and r.name == name]
+                            for name in ("serve.tick.call",
+                                         "serve.tick.unpack"))
+            ticks[k] = reqs, futs, ref, call[0], unpack[0]
     return ticks
 
 
 @pytest.mark.parametrize("k", KS)
 def test_tick_matches_host_stacked_batch_bitwise(ticked, k):
-    """A tick's device-assembled batch solves to exactly what the same
-    executable returns on the host-stacked numpy batch of the same
-    slots, zero-filled past the ``k`` admitted requests: the empty slots'
-    repeats of the first slot reach no result, and the per-slot fetch
-    brings each admitted slot's W unchanged."""
-    reqs, futs, ref, _ = ticked[k]
+    """A tick's device-assembled batch solves to exactly what the
+    executable of the rung it runs returns on the host-stacked numpy
+    batch of the same slots, zero-filled past the ``k`` admitted
+    requests: the empty slots' repeats of the first slot reach no
+    result, and the per-slot fetch brings each admitted slot's W
+    unchanged."""
+    reqs, futs, ref, call, _ = ticked[k]
+    assert call.attrs["slots"] == len(ref["final_loss"])
     d = resolve_task(CFG).dim
     for i, (r, f) in enumerate(zip(reqs, futs)):
         res = f.result()
@@ -339,8 +348,8 @@ def test_tick_matches_host_stacked_batch_bitwise(ticked, k):
 def test_unpack_counts_the_bytes_it_brings(ticked, k):
     """``bytes_out`` of ``serve.tick.unpack``: the ``k`` admitted slots'
     W (n_pad × dim float32 each; the empty slots' W is not fetched) and
-    the small leaves of all 8 slots."""
-    _, _, ref, unpack = ticked[k]
+    the small leaves of all the rung's slots."""
+    _, _, ref, _, unpack = ticked[k]
     small = sum(v.nbytes for key, v in ref.items() if key != "W")
     assert unpack.attrs["bytes_out"] == (
         k * 8 * resolve_task(CFG).dim * 4 + small)
@@ -415,6 +424,21 @@ def test_metrics_summary_fields(trained):
     # useful 3*6*4 cells of 4*8*4 padded slots
     assert s["pad_waste"] == pytest.approx(1 - 72 / 128)
     assert s["per_bucket_ticks"] == {"n8xt4": 1}
+    assert s["ticks_by_slots"] == {4: 1}
+
+
+def test_metrics_count_the_slots_of_the_rung_run(trained):
+    """One request on a ``max_batch=4`` server runs the one-slot rung:
+    every slot run is occupied, and only that slot's cells are padded."""
+    state, _ = trained
+    srv = _server(state.theta)
+    _, S, ds = _cohort(6, 4, seed=63)
+    srv.submit(S, ds, seed=0)
+    srv.drain()
+    s = srv.metrics.summary()
+    assert s["occupancy"] == 1.0
+    assert s["ticks_by_slots"] == {1: 1}
+    assert s["pad_waste"] == pytest.approx(1 - 24 / 32)
 
 
 # ----------------------------------------------------------- validation
@@ -458,15 +482,17 @@ def test_serve_cache_key_shape_and_task_separation():
 
 
 def test_bucket_cache_lru_eviction_and_stats(trained):
+    """``max_buckets`` counts buckets: the cache holds that many buckets'
+    executables at each of the server's 3 rungs."""
     state, _ = trained
     srv = _server(state.theta, max_buckets=1)
     srv.warm([(6, 4)])
-    srv.warm([(12, 4)])                 # evicts the (8,4) executable
+    srv.warm([(12, 4)])                 # evicts the (8,4) executables
     st = srv.cache_stats()
-    assert st["size"] == 1 and st["evictions"] == 1
+    assert st["size"] == 3 and st["evictions"] == 3
     base = E.TRACE_COUNTS["serve"]
     srv.warm([(6, 4)])                  # rebuild after eviction: retrace
-    assert E.TRACE_COUNTS["serve"] - base == 1
+    assert E.TRACE_COUNTS["serve"] - base == 3
 
 
 def test_clear_caches_selective_and_stats(trained):
@@ -476,7 +502,7 @@ def test_clear_caches_selective_and_stats(trained):
     name = srv._cache.name
     assert name.startswith("serve-buckets")
     stats = cache_stats()
-    assert stats[name]["size"] == 1
+    assert stats[name]["size"] == len(srv.rungs)
     assert "engine" in stats and "surf-eval" in stats
     engine_size = stats["engine"]["size"]
     # selective clear: ONLY the named serve cache empties
@@ -581,32 +607,63 @@ def test_federations_per_sec_is_over_wall_time():
 
 
 # ------------------------------------------------------- async driver
-def test_async_driver_matches_manual_tick_loop(trained):
-    """The background tick loop adds no scheduling of its own: the same
-    submission order yields the same per-request results as a manual
-    tick loop (padding is inert, so batch composition never matters)."""
-    state, _ = trained
+def _async_vs_manual(theta, queue_first):
+    """Ten mixed-bucket requests through a manual ``drain()`` and through
+    an ``AsyncDriver``: (manual results, async results, driver stats).
+    With ``queue_first`` every request is queued before the tick loop
+    starts; otherwise the submits land while it ticks."""
     reqs = [_cohort([6, 8, 12, 16][i % 4], 4, seed=70 + i)
             for i in range(10)]
 
-    manual = _server(state.theta)
+    manual = _server(theta)
     m_futs = [manual.submit(S, ds, seed=i)
               for i, (_, S, ds) in enumerate(reqs)]
     manual.drain()
 
-    srv = _server(state.theta)
-    with AsyncDriver(srv) as driver:
+    driver = AsyncDriver(_server(theta))
+    if queue_first:
         a_futs = [driver.submit(S, ds, seed=i)
                   for i, (_, S, ds) in enumerate(reqs)]
-        driver.wait(a_futs, timeout_s=120.0)
-    for mf, af in zip(m_futs, a_futs):
-        m, a = mf.result(), af.result()
-        np.testing.assert_array_equal(np.asarray(m["final_loss"]),
-                                      np.asarray(a["final_loss"]))
-        np.testing.assert_array_equal(np.asarray(m["final_acc"]),
-                                      np.asarray(a["final_acc"]))
-    stats = driver.stats()
-    assert stats["requests_completed"] == len(reqs)
+        with driver:
+            driver.wait(a_futs, timeout_s=120.0)
+    else:
+        with driver:
+            a_futs = [driver.submit(S, ds, seed=i)
+                      for i, (_, S, ds) in enumerate(reqs)]
+            driver.wait(a_futs, timeout_s=120.0)
+    return ([f.result() for f in m_futs], [f.result() for f in a_futs],
+            driver.stats())
+
+
+def test_async_driver_matches_manual_tick_loop(trained):
+    """The background tick loop adds no scheduling of its own: submits
+    landing while it ticks give the same per-request results as a manual
+    tick loop over the same submission order. Padding is inert, but a
+    tick that admits fewer requests runs a smaller rung, whose
+    executable may round differently, so the results agree within the
+    1e-5 that ``tests/test_serve_theta_split.py`` holds rungs to."""
+    state, _ = trained
+    manual, served, stats = _async_vs_manual(state.theta, queue_first=False)
+    for m, a in zip(manual, served):
+        for key in ("final_loss", "final_acc"):
+            np.testing.assert_allclose(np.asarray(a[key]),
+                                       np.asarray(m[key]), rtol=0,
+                                       atol=1e-5)
+    assert stats["requests_completed"] == len(manual)
+    assert stats["ticks"] >= 1 and not stats["running"]
+
+
+def test_async_driver_on_a_queued_stream_matches_manual_bitwise(trained):
+    """On a queue filled before the loop starts the driver admits the
+    same ticks as ``drain()``, so each request runs at the same rung and
+    its results are bit-identical."""
+    state, _ = trained
+    manual, served, stats = _async_vs_manual(state.theta, queue_first=True)
+    for m, a in zip(manual, served):
+        for key in ("final_loss", "final_acc"):
+            np.testing.assert_array_equal(np.asarray(a[key]),
+                                          np.asarray(m[key]))
+    assert stats["requests_completed"] == len(manual)
     assert stats["ticks"] >= 1 and not stats["running"]
 
 

@@ -7,12 +7,15 @@ The subprocess (``_probe``) serves one tiny 62-class federation set
 (F = 8, n = 12, L = 2: d = 558, which 4 does not divide) through
 ``FederationServer`` on one device, on meshes that split θ 2 and 4 ways
 and on one that splits request slots 4 ways, in ticks of 1, 3 and 8
-admitted requests, and reports what the tests below compare."""
+admitted requests, then of every count from 1 to 8 to read the rung each
+tick runs, and reports what the tests below compare."""
 import dataclasses
 import json
 import os
 import subprocess
 import sys
+import tempfile
+import time
 import types
 from pathlib import Path
 
@@ -32,7 +35,13 @@ SPLITS = (2, 4)
 LAYOUTS = {"one": None, "split_2": (2, 2), "split_4": (1, 4),
            "requests_4": (4, 1)}
 KS = (1, 3, 8)
-WARM_KS = (1, 3, 4)
+WARM_KS = (1, 2, 3, 4)
+# the rung a tick of k = 1..max_batch admitted requests runs, by layout
+RUNGS = {"one": (1, 2, 4, 4, 8, 8, 8, 8),
+         "split_2": (2, 2, 4, 4, 8, 8, 8, 8),
+         "split_4": (1, 2, 4, 4, 8, 8, 8, 8),
+         "requests_4": (4, 4, 4, 4, 8, 8, 8, 8),
+         "max_batch_3": (1, 2, 3)}
 V5E_LIMIT = 15.75e9
 SEED = 2 ** 40 + 12345
 
@@ -102,6 +111,12 @@ def _probe():
                     for a, b in zip(served[layout][k], whole) for key in b)
             out[f"w_shapes_{layout}"] = [
                 list(r["W"].shape) for k in KS for r in served[layout][k]]
+            # request 0 alone (the smallest rung) against it in a full batch
+            out[f"rung_gap_{layout}"] = max(
+                float(np.max(np.abs(served[layout][1][0][key]
+                                    - served[layout][8][0][key])))
+                for key in ("W", "final_loss", "loss_per_layer"))
+            out[f"rungs_{layout}"] = _rungs_run(srv, reqs, srv.max_batch)
             if shape is None:
                 continue
             out[f"one_gap_{layout}"] = max(
@@ -121,10 +136,11 @@ def _probe():
             # the solver's own output, padded columns included
             bucket = buckets.bucket_for(12, 4)
             slot = srv._slot(reqs[0][1], reqs[0][2], bucket, 0, 0)
-            stacked, m, t = srv._batch(bucket, [slot], [np.ones(16, bool)],
-                                       [np.float32(4)])
-            raw = srv._solver(bucket)(stacked[0], srv.theta, *stacked[1:],
-                                      m, t)
+            B = srv.rungs[0]
+            stacked, m, t = srv._batch(bucket, B, [slot],
+                                       [np.ones(16, bool)], [np.float32(4)])
+            raw = srv._solver(bucket, B)(stacked[0], srv.theta,
+                                         *stacked[1:], m, t)
             W = np.asarray(raw["W"])
             out[f"cols_{split}"] = W.shape[-1]
             out[f"pad_max_{split}"] = float(np.abs(W[..., d:]).max(
@@ -134,14 +150,21 @@ def _probe():
             out[f"theta_block_{split}"] = list(
                 srv.theta["M"].addressable_shards[0].data.shape)
 
-    # after warm(), a tick on the 2-D mesh traces and compiles nothing
+        srv = FederationServer(CFG, theta, max_batch=3, buckets=buckets)
+        out["rungs_max_batch_3"] = _rungs_run(srv, reqs, 3)
+
+    # warm() traces once a rung; after it, a tick on the 2-D mesh of any
+    # admitted count traces and compiles nothing
     compiles = []
     jax.monitoring.register_event_duration_secs_listener(
         lambda e, dur, **_: compiles.append(e)
         if e in harness.COMPILE_EVENTS else None)
     srv = FederationServer(CFG, theta, max_batch=4, buckets=buckets,
                            mesh=mesh((2, 2)))
+    traces = TRACE_COUNTS["serve"]
     srv.warm([(12, 4), (10, 4)])
+    out["warm_rungs"] = list(srv.rungs)
+    out["warm_traces"] = TRACE_COUNTS["serve"] - traces
     traces, n_compiles = TRACE_COUNTS["serve"], len(compiles)
     done = 0
     for k in WARM_KS:
@@ -186,18 +209,41 @@ def _probe():
     print(json.dumps(out))
 
 
+def _rungs_run(srv, reqs, max_batch):
+    """The rung (``slots`` of its ``serve.tick.call`` span) of one tick of
+    each admitted count 1..``max_batch``, and whether the tick's
+    ``ticks_by_slots`` count agrees."""
+    import jax
+    from repro.utils import spans
+    rungs = []
+    with tempfile.TemporaryDirectory() as trace_dir, \
+            jax.profiler.trace(trace_dir):
+        for k in range(1, max_batch + 1):
+            before = dict(srv.metrics.ticks_by_slots)
+            for i, (_, S, ds) in enumerate(reqs[:k]):
+                srv.submit(S, ds, seed=i)
+            t0 = time.perf_counter()
+            assert srv.tick() == k
+            (call,) = [r for r in spans.records()
+                       if r.t0 >= t0 and r.name == "serve.tick.call"]
+            B = call.attrs["slots"]
+            counted = srv.metrics.ticks_by_slots[B] - before.get(B, 0)
+            rungs.append(B if counted == 1 else None)
+    return rungs
+
+
 def _whole_fetch(srv, d):
     """The queued requests' results as the solver's whole output, fetched
     at once and then sliced: the batch ``tick`` would make of the queue,
-    solved by the same executable."""
+    solved by the executable of the rung it would run."""
     import jax
     reqs = list(srv._queue)
-    bucket = reqs[0].bucket
-    stacked, m, t = srv._batch(bucket, [r.arrays for r in reqs],
+    bucket, B = reqs[0].bucket, srv._rung(len(reqs))
+    stacked, m, t = srv._batch(bucket, B, [r.arrays for r in reqs],
                                [r.mask for r in reqs],
                                [r.t_real for r in reqs])
-    host = jax.device_get(srv._solver(bucket)(stacked[0], srv.theta,
-                                              *stacked[1:], m, t))
+    host = jax.device_get(srv._solver(bucket, B)(stacked[0], srv.theta,
+                                                 *stacked[1:], m, t))
     res = [{key: v[i] for key, v in host.items() if key != "W"}
            for i in range(len(reqs))]
     for i, r in enumerate(reqs):
@@ -270,9 +316,35 @@ def test_request_split_server_matches_single_device_server(probe):
 
 
 def test_tick_after_warm_traces_and_compiles_nothing(probe):
+    """``warm()`` traces the solver once a (bucket, rung): one bucket, and
+    rungs 2 and 4 where the request axis splits 2 ways; ticks of 1 to 4
+    admitted requests, every rung among them, then trace and compile
+    nothing."""
+    assert probe["warm_rungs"] == [2, 4]
+    assert probe["warm_traces"] == 2
     assert probe["after_warm_done"] == sum(WARM_KS)
     assert probe["after_warm_traces"] == 0
     assert probe["after_warm_compiles"] == 0
+
+
+@pytest.mark.parametrize("layout,k", [(layout, k)
+                                      for layout, rungs in RUNGS.items()
+                                      for k in range(1, len(rungs) + 1)])
+def test_tick_runs_the_smallest_rung_that_holds_the_admitted(probe, layout,
+                                                            k):
+    """A tick of ``k`` admitted requests runs the smallest rung of
+    ``shards`` × 2^j below ``max_batch``, then ``max_batch``, that holds
+    them: as its ``serve.tick.call`` span's ``slots`` and its
+    ``ticks_by_slots`` count both read."""
+    assert probe[f"rungs_{layout}"][k - 1] == RUNGS[layout][k - 1]
+
+
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_one_request_alone_matches_it_in_a_full_batch(probe, layout):
+    """Request 0 served alone (the smallest rung) and among 8 (the full
+    batch): the same numbers, within what the two executables' rounding
+    allows."""
+    assert probe[f"rung_gap_{layout}"] < 1e-5
 
 
 def test_host_theta_is_placed_block_by_block(probe):

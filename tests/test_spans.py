@@ -229,6 +229,7 @@ def test_served_tick_spans_nest_and_join(served_tick):
     call = by["serve.tick.call"][0]
     assert all(isinstance(a, jax.Array) for a in queued[0])
     assert call.attrs["bytes_in"] == 4 * (8 * 1 + 4)
+    assert call.attrs["slots"] == 4     # the smallest rung holding 3
     # latency runs from submit's entry, so featurization counts in it
     wait = by["serve.tick.wait"][0]
     for f, sub in zip(futs, submits):
